@@ -120,7 +120,7 @@ scrub-check:
 	timeout -k 10 600 $(JAX_ENV) $(PYTHON) -m deepflow_tpu.cli.scrub_check
 
 bench:
-	$(JAX_ENV) $(PYTHON) bench.py
+	$(PYTHON) bench.py
 
 # Build every native library, then fail loudly if the freshly-built
 # libdfnative.so does not load at the ABI the python bindings expect —

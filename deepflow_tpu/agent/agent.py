@@ -25,8 +25,12 @@ log = logging.getLogger("df.agent")
 
 
 class Agent:
-    def __init__(self, config: AgentConfig | None = None) -> None:
+    def __init__(self, config: AgentConfig | None = None,
+                 in_process: bool = False) -> None:
         self.config = config or AgentConfig()
+        # attached inside the job it profiles (deepflow-run / attach):
+        # the process's CPU and RSS are the job's, not the agent's
+        self.in_process = in_process
         self.process_name = os.path.basename(sys.argv[0]) or "python"
         self.app_service = self.config.app_service or self.process_name
         # self-telemetry spine: hop ledger + heartbeats + deadman
@@ -65,6 +69,7 @@ class Agent:
         self.memprofiler = None
         self.extprofilers: list = []
         self.tpuprobe = None
+        self.tpuprobe_calibration = None  # kept across guard pauses
         self.synchronizer = None
         self.socket_scanner = None
         self.guard = None
@@ -164,8 +169,8 @@ class Agent:
                 return
             try:
                 from deepflow_tpu.tpuprobe.probe import TpuProbe
-            except ImportError:
-                log.debug("tpuprobe unavailable")
+            except ImportError as e:
+                log.warning("tpuprobe unavailable: %s", e)
                 return
             self.tpuprobe = TpuProbe(self).start()
 
@@ -373,7 +378,8 @@ class Agent:
             g = self.config.guard
             self.guard = Guard(
                 self, max_cpu_pct=g.max_cpu_pct, max_mem_mb=g.max_mem_mb,
-                check_interval_s=g.check_interval_s).start()
+                check_interval_s=g.check_interval_s,
+                in_process=self.in_process).start()
             self._components.append("guard")
         if self.config.controller:
             from deepflow_tpu.agent.synchronizer import Synchronizer
@@ -572,7 +578,7 @@ def attach(app_service: str = "", servers: list | None = None,
         cfg.sender.servers = servers
     for k, v in overrides.items():
         setattr(cfg, k, v)
-    _GLOBAL_AGENT = Agent(cfg).start()
+    _GLOBAL_AGENT = Agent(cfg, in_process=True).start()
     # interpreter teardown with a live xplane capture aborts the process
     # (daemon thread inside jax.profiler during shutdown): detach cleanly.
     # Registered once per process; detach() is idempotent.
@@ -581,6 +587,11 @@ def attach(app_service: str = "", servers: list | None = None,
         import atexit
         atexit.register(detach)
         _ATEXIT_REGISTERED = True
+    return _GLOBAL_AGENT
+
+
+def current() -> Agent | None:
+    """The agent attach() started in this process, if any."""
     return _GLOBAL_AGENT
 
 

@@ -32,7 +32,7 @@ class ProfilerConfig:
 @dataclass
 class TpuProbeConfig:
     enabled: bool = True
-    source: str = "auto"          # auto | xplane | hooks | sim
+    source: str = "auto"          # auto (= xplane) | xplane | hooks | sim
     trace_interval_s: float = 10.0  # fallback cadence before steps observed
     trace_duration_ms: int = 1000
     # step-adaptive duty cycle: windows sized to whole steps, gaps sized so
@@ -356,7 +356,11 @@ _TEMPLATE_DOCS = {
     "plugins": "parser plugin modules exporting PARSERS",
     "profiler.sample_hz": "OnCPU sampling rate",
     "profiler.external_pids": "out-of-process perf targets (any pid)",
-    "tpuprobe.source": "auto | xplane | hooks | sim",
+    "tpuprobe.source": "auto (= xplane) | xplane | hooks | sim",
+    "guard.max_cpu_pct": "in a job (deepflow-run / attach): the agent's "
+                         "df-* threads; standalone: the process",
+    "guard.max_mem_mb": "in a job: bytes the agent's sender buffers; "
+                        "standalone: process RSS",
     "tpuprobe.target_coverage": "fraction of steps captured (0.01-0.95)",
     "tpuprobe.steps_per_capture": "whole steps per capture window",
     "tpuprobe.step_metrics": "emit per-(run_id, step) STEP_METRICS rollups",

@@ -33,12 +33,36 @@ def read_self_usage() -> tuple[float, int]:
     return cpu_s, rss_pages * _PAGE
 
 
+def read_agent_threads_cpu_s(prefix: str = "df-") -> float:
+    """CPU seconds of this process's agent threads (named df-*): what an
+    agent attached INSIDE a job costs, without the job's own threads."""
+    import threading
+    total = 0
+    for t in threading.enumerate():
+        if not t.name.startswith(prefix) or not t.native_id:
+            continue
+        try:
+            with open(f"/proc/self/task/{t.native_id}/stat") as f:
+                parts = f.read().rsplit(") ", 1)[1].split()
+        except OSError:
+            continue  # the thread exited since enumerate()
+        total += int(parts[11]) + int(parts[12])
+    return total / _CLK_TCK
+
+
 class Guard:
     def __init__(self, agent, max_cpu_pct: float = 50.0,
                  max_mem_mb: float = 2048.0,
                  check_interval_s: float = 10.0,
-                 recover_ratio: float = 0.8) -> None:
+                 recover_ratio: float = 0.8,
+                 in_process: bool = False) -> None:
         self.agent = agent
+        # in_process: the agent shares its process with the job it
+        # profiles (deepflow-run / attach), so the process's CPU and RSS
+        # are the job's. Police the agent's own threads' CPU, and hold
+        # max_mem_mb to the bytes the agent buffers (its sender's queue,
+        # ack window and resends): no per-thread RSS exists.
+        self.in_process = in_process
         self.max_cpu_pct = max_cpu_pct
         self.max_mem_mb = max_mem_mb
         self.check_interval_s = check_interval_s
@@ -47,7 +71,8 @@ class Guard:
         self.degraded = False
         self.cpu_pct = 0.0
         self.rss_mb = 0.0
-        self.stats = {"checks": 0, "degrades": 0, "recoveries": 0}
+        self.stats = {"checks": 0, "degrades": 0, "recoveries": 0,
+                      "peak_cpu_pct": 0.0}
         self._last: tuple[float, float] | None = None  # (mono, cpu_s)
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
@@ -73,11 +98,17 @@ class Guard:
     def check(self, now: float | None = None) -> None:
         self.stats["checks"] += 1
         cpu_s, rss = read_self_usage()
+        if self.in_process:
+            cpu_s = read_agent_threads_cpu_s()
+            rss = self.agent.sender.buffered_bytes()
         mono = now if now is not None else time.monotonic()
         if self._last is not None:
             dt = mono - self._last[0]
             if dt > 0:
-                self.cpu_pct = 100.0 * (cpu_s - self._last[1]) / dt
+                # agent threads that exit take their CPU time with them
+                self.cpu_pct = max(0.0, 100.0 * (cpu_s - self._last[1]) / dt)
+                self.stats["peak_cpu_pct"] = max(
+                    self.stats["peak_cpu_pct"], self.cpu_pct)
         self._last = (mono, cpu_s)
         self.rss_mb = rss / (1024 * 1024)
         self._evaluate()

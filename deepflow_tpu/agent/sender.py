@@ -176,6 +176,14 @@ class UniformSender:
     def queue_depth(self) -> int:
         return self._q.qsize()
 
+    def buffered_bytes(self) -> int:
+        """Payload bytes held in memory: queued, awaiting an ack or a
+        resend (the spool is on disk)."""
+        with self._q.mutex:
+            held = list(self._q.queue)
+        held += list(self._unacked.values()) + list(self._pending)
+        return sum(len(f.payload) for f in held)
+
     def peek(self, n: int = 8) -> list:
         """Non-consuming sample of queued frames (debug queue tap)."""
         with self._q.mutex:
@@ -724,6 +732,10 @@ class ReplicatedSender:
         with self._lock:
             return max((s.queue_depth()
                         for s in self._senders.values()), default=0)
+
+    def buffered_bytes(self) -> int:
+        with self._lock:
+            return sum(s.buffered_bytes() for s in self._senders.values())
 
     def peek(self, n: int = 8) -> list:
         with self._lock:

@@ -192,10 +192,11 @@ class Synchronizer:
             sent_results = list(self._pending_results)
         for r in sent_results:
             req.command_results.append(r)
-        # collect topology once, but RE-SEND every sync: a restarted
+        # collect topology until it lists devices (the job may bring JAX
+        # up after the first sync), but RE-SEND every sync: a restarted
         # controller must be able to rebuild its platform/gpid state from
         # long-lived agents (the request is tiny)
-        if self._platform_cache is None:
+        if self._platform_cache is None or not self._platform_cache.devices:
             from deepflow_tpu.tpuprobe.topology import collect_platform_data
             self._platform_cache = collect_platform_data()
         req.platform.CopyFrom(self._platform_cache)

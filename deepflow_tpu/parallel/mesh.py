@@ -42,18 +42,9 @@ def make_mesh(devices=None, shape: tuple[int, int, int] | None = None) -> Mesh:
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable jax shard_map.
-
-    jax >= 0.6 exposes ``jax.shard_map`` with a ``check_vma`` kwarg; older
-    releases only have ``jax.experimental.shard_map.shard_map`` where the
-    same switch is spelled ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """jax.shard_map with replication checking off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def shard_params(params, specs, mesh: Mesh):

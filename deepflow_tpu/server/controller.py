@@ -658,6 +658,14 @@ class Controller:
             org_id=self.org_of_group(self.registry.group_of(agent_id)),
         ))
 
+    def devices_of(self, agent_id: int) -> list[dict]:
+        """The TPU devices an agent last reported in its platform data."""
+        with self._platform_lock:
+            p = self._platforms.get(agent_id)
+            return [{"device_id": d.device_id, "device_kind": d.device_kind,
+                     "hbm_bytes": d.hbm_bytes}
+                    for d in (p.devices if p is not None else ())]
+
     def _merged_platform_locked(self) -> pb.PlatformData:
         merged = pb.PlatformData()
         for p in self._platforms.values():

@@ -1496,6 +1496,7 @@ class QuerierAPI:
                 "agent_group": a.get("agent_group", "default"),
                 "config_version": a.get("config_version", 0),
                 "syncs": a.get("syncs", 0),
+                "devices": self.controller.devices_of(a["agent_id"]),
             })
         return {"agents": out}
 

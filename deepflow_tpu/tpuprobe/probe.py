@@ -46,17 +46,21 @@ class TpuProbe:
 
     def start(self) -> "TpuProbe":
         mode = self.cfg.source
-        if mode == "auto":
-            mode = "sim" if os.environ.get("DFTPU_SIM") else "xplane"
-        if mode == "xplane":
+        if mode in ("auto", "xplane"):
+            # deepflow-run attaches before the job imports JAX: the
+            # capture loop retries the hooks until they register
+            hooks = HooksSource(self._sink).start()
             self.sources.append(XPlaneSource(
                 self._sink,
                 interval_s=self.cfg.trace_interval_s,
                 duration_ms=self.cfg.trace_duration_ms,
                 target_coverage=self.cfg.target_coverage,
                 steps_per_capture=self.cfg.steps_per_capture,
-                telemetry=self.telemetry).start())
-            self.sources.append(HooksSource(self._sink).start())
+                calibration=getattr(self.agent, "tpuprobe_calibration",
+                                    None),
+                telemetry=self.telemetry,
+                on_cycle=hooks.start).start())
+            self.sources.append(hooks)
             if self.cfg.memory_poll_s > 0:
                 self.sources.append(MemorySource(
                     self._mem_sink,
@@ -78,6 +82,9 @@ class TpuProbe:
             stop = getattr(s, "stop", None)
             if stop:
                 stop()
+            if isinstance(s, XPlaneSource):
+                # a probe the guard paused resumes with this cadence
+                self.agent.tpuprobe_calibration = s.calibration()
         if self.stepagg:
             self.stepagg.flush()  # ship the last (still-open) step
 

@@ -10,17 +10,14 @@
 
 from __future__ import annotations
 
-import glob
 import logging
-import os
-import shutil
-import tempfile
+import sys
 import threading
 import time
 
 from deepflow_tpu.proto import pb
 from deepflow_tpu.tpuprobe.events import TpuSpanEvent
-from deepflow_tpu.tpuprobe.xplane import parse_xplane_file
+from deepflow_tpu.tpuprobe.xplane import extract_device_spans, parse_xspace
 
 log = logging.getLogger("df.tpuprobe")
 
@@ -29,6 +26,24 @@ log = logging.getLogger("df.tpuprobe")
 # capture must never collide with a second source in this process, and a
 # session started by USER code must make us skip, not crash
 _PROFILER_SESSION_LOCK = threading.Lock()
+
+
+def jax_imported() -> bool:
+    """Has the job's `import jax` completed? Another thread that imports
+    from jax while the job is still importing it hands the job partly
+    initialized modules, so wait until the import is done."""
+    jax = sys.modules.get("jax")
+    return jax is not None and not getattr(
+        getattr(jax, "__spec__", None), "_initializing", False)
+
+
+def jax_backends_initialized() -> bool:
+    """Has this process's JAX brought its backends up? Never imports JAX
+    and never initializes a backend itself."""
+    if not jax_imported():
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
 
 
 class XPlaneSource:
@@ -55,8 +70,13 @@ class XPlaneSource:
                  steps_per_capture: int = 20,
                  min_duration_ms: int = 200,
                  max_duration_ms: int = 4000,
-                 min_gap_ms: int = 200, telemetry=None) -> None:
+                 min_gap_ms: int = 200, calibration: dict | None = None,
+                 telemetry=None,
+                 on_cycle=None) -> None:
         self.sink = sink
+        # called at the top of every loop cycle (the probe registers its
+        # jax.monitoring hooks from here once the job has imported JAX)
+        self._on_cycle = on_cycle
         if telemetry is None:
             from deepflow_tpu.telemetry import Telemetry
             telemetry = Telemetry("agent", enabled=False)
@@ -68,6 +88,14 @@ class XPlaneSource:
         self.min_duration_ms = min_duration_ms
         self.max_duration_ms = max_duration_ms
         self.min_gap_ms = min_gap_ms
+        # the capture thread's busy time (session stop, parse, sink) stays
+        # under this share of wall time, whatever coverage that leaves:
+        # on 4 chips a window's parse outlasts the window, and chasing
+        # target_coverage kept the agent busy past its guard's CPU limit
+        self.max_busy_share = 0.2
+        # and no single cycle's busy time outgrows this: windows shrink
+        # until their predicted parse fits (the guard reads 10 s spans)
+        self.max_dead_ms = 1500
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._step_time_s = 0.0             # estimated from module spans
@@ -78,22 +106,39 @@ class XPlaneSource:
         # dead + window + gap, so the gap must shrink by the measured
         # dead time and windows must stretch to amortize it.
         self._dead_s = 0.0
+        # dead seconds per second of window: the parse and sink grow
+        # with the window's events, the rest of the dead time does not
+        self._dead_per_s = 0.0
+        # local devices, read at the first capture: an uncalibrated
+        # window's events (and parse time) grow with them
+        self._n_devices = 1
+        # carried over from a probe the guard paused: the restarted
+        # source opens with the measured cadence, not the fallback window
+        if calibration:
+            self._step_time_s = calibration["step_time_s"]
+            self._dead_s = calibration["dead_s"]
+            self._dead_per_s = calibration["dead_per_s"]
         self._started_monotonic = time.monotonic()
+        # span_windows: captures that yielded device op spans;
+        # planes_without_ops: TPU device planes with no "XLA Ops" line;
+        # unknown_device_planes: /device: planes the parser skipped
         self.stats = {"captures": 0, "events": 0, "errors": 0, "skipped": 0,
-                      "contended": 0, "steps_seen": 0,
+                      "contended": 0, "span_windows": 0,
+                      "planes_without_ops": 0, "unknown_device_planes": 0,
+                      "steps_seen": 0,
                       "coverage_pct": 0.0, "est_step_ms": 0.0,
-                      "captured_s": 0.0, "dead_ms": 0.0}
+                      "captured_s": 0.0, "dead_ms": 0.0,
+                      "stop_ms": 0.0, "parse_ms": 0.0, "sink_ms": 0.0}
+
+    def calibration(self) -> dict:
+        """What a restarted source needs to skip the fallback window."""
+        return {"step_time_s": self._step_time_s, "dead_s": self._dead_s,
+                "dead_per_s": self._dead_per_s}
 
     def available(self) -> bool:
-        import sys
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return False
-        try:
-            from jax._src import xla_bridge
-            return xla_bridge.backends_are_initialized()
-        except Exception:
-            return True  # optimistic: profiler start will tell us
+        """True once the job itself has initialized JAX's backends: a
+        capture never imports JAX or takes the chip first."""
+        return jax_backends_initialized()
 
     def start(self) -> "XPlaneSource":
         self._thread = threading.Thread(
@@ -123,40 +168,55 @@ class XPlaneSource:
             # (profiler wedge) freezes the progress counter and trips the
             # deadman, instead of looking like a long gap
             hb.beat(progress=self.stats["captures"] + self.stats["skipped"])
+            if self._on_cycle is not None:
+                self._on_cycle()
             if self.available():
                 try:
                     self.capture_once()
                 except Exception:
                     self.stats["errors"] += 1
                     log.exception("xplane capture failed")
+                gap = self._next_gap_s()
             else:
+                # the job has not brought JAX up yet: look again soon, so
+                # the first windows and the hooks follow it within a second
                 self.stats["skipped"] += 1
-            if self._stop.wait(self._next_gap_s()):
+                gap = min(1.0, self._next_gap_s())
+            if self._stop.wait(gap):
                 return
 
     def _next_duration_s(self) -> float:
         """Window sized to cover `steps_per_capture` whole steps — and at
         least long enough that the fixed per-cycle dead time plus the
         minimum gap fit inside the non-covered share of the cycle
-        (coverage = dur / (dur + dead + gap))."""
+        (coverage = dur / (dur + dead + gap)) — and at most as long as
+        keeps the cycle's predicted dead time under max_dead_ms."""
         if self._step_time_s <= 0:
-            return self.duration_ms / 1000.0
-        want = self._step_time_s * self.steps_per_capture
-        t = self.target_coverage
-        amortize = t * (self._dead_s + self.min_gap_ms / 1000.0) / (1.0 - t)
-        want = max(want, amortize)
+            want = self.duration_ms / 1000.0 / self._n_devices
+        else:
+            want = self._step_time_s * self.steps_per_capture
+            t = self.target_coverage
+            amortize = (t * (self._dead_s + self.min_gap_ms / 1000.0)
+                        / (1.0 - t))
+            want = max(want, amortize)
+        if self._dead_per_s > 0:
+            want = min(want, self.max_dead_ms / 1000.0 / self._dead_per_s)
         return min(max(want, self.min_duration_ms / 1000.0),
                    self.max_duration_ms / 1000.0)
 
     def _next_gap_s(self) -> float:
         """Gap between windows for the target step coverage. The real
         cycle is dead + duration + gap, so the measured dead time comes
-        out of the gap budget."""
-        if self._step_time_s <= 0:
-            return self.interval_s  # cadence unknown: conservative fallback
+        out of the gap budget — but never so far that the dead time
+        exceeds max_busy_share of the cycle."""
         dur = self._next_duration_s()
+        dead = max(self._dead_s, self._dead_per_s * dur)
+        busy_gap = dead / self.max_busy_share - dead - dur
+        if self._step_time_s <= 0:
+            # cadence unknown: conservative fallback
+            return max(self.interval_s, busy_gap)
         gap = dur * (1.0 / self.target_coverage - 1.0) - self._dead_s
-        return max(gap, self.min_gap_ms / 1000.0)
+        return max(gap, busy_gap, self.min_gap_ms / 1000.0)
 
     def _observe(self, events: list, wall_s: float) -> None:
         """Update the step-cadence estimate from a capture's module spans."""
@@ -178,66 +238,81 @@ class XPlaneSource:
 
     def capture_once(self) -> list[TpuSpanEvent]:
         import jax
+        from jax._src import profiler as jax_profiler
+        from jax._src.lib import _profiler
 
         if not _PROFILER_SESSION_LOCK.acquire(blocking=False):
             self.stats["contended"] += 1
             return []
-        tmpdir = tempfile.mkdtemp(prefix="dftpu-xplane-")
+        self._n_devices = max(1, jax.local_device_count())
+        state = jax_profiler._profile_state
         t0_ns = time.time_ns()
         t0 = time.monotonic()
         try:
-            try:
-                # device planes are all we parse: host/python tracers only
-                # add overhead to the workload while the window is open
-                opts = None
-                try:
-                    opts = jax.profiler.ProfileOptions()
-                    opts.host_tracer_level = 0
-                    opts.python_tracer_level = 0
-                    opts.enable_hlo_proto = False
-                except (AttributeError, ImportError):
-                    pass  # older jax: default options
-                if opts is not None:
-                    jax.profiler.start_trace(tmpdir, profiler_options=opts)
-                else:
-                    jax.profiler.start_trace(tmpdir)
-            except Exception as e:
-                # only a genuinely-busy singleton counts as contention;
-                # a broken profiler must stay loud (errors + log)
-                if "already" in str(e).lower() or \
-                        "in progress" in str(e).lower():
+            # device planes are all we parse: host/python tracers only
+            # add overhead to the workload while the window is open
+            opts = jax.profiler.ProfileOptions()
+            opts.host_tracer_level = 0
+            opts.python_tracer_level = 0
+            opts.enable_hlo_proto = False
+            # jax.profiler.start_trace's session, without its export on
+            # stop: writing xplane.pb and converting it to trace.json.gz
+            # tripled the stop's CPU. Registered where start_trace looks,
+            # so a user's trace and ours still exclude each other.
+            with state.lock:
+                if state.profile_session is not None:
                     self.stats["contended"] += 1
-                else:
+                    return []
+                try:
+                    session = _profiler.ProfilerSession(opts)
+                except Exception:
                     self.stats["errors"] += 1
-                    log.exception("xplane start_trace failed")
-                return []
+                    log.exception("xplane profiler session failed")
+                    return []
+                state.profile_session = session
             # sleep through the window; workload threads keep running.
-            # The covered span is the open-trace wait only — start_trace
-            # setup and stop_trace export are dead time.
+            # The covered span is the open-trace wait only — session
+            # setup, stop, parse and sink are dead time.
             window_t0 = time.monotonic()
             self._stop.wait(self._next_duration_s())
             window_s = time.monotonic() - window_t0
-            jax.profiler.stop_trace()
+            with state.lock:
+                state.profile_session = None
+                xspace = session.stop()
+            t_stop = time.monotonic()
             self._captured_s += window_s
-            events: list[TpuSpanEvent] = []
-            for path in glob.glob(
-                    os.path.join(tmpdir, "plugins/profile/*/*.xplane.pb")):
-                events.extend(parse_xplane_file(path, capture_start_ns=t0_ns))
+            events = extract_device_spans(
+                parse_xspace(xspace), capture_start_ns=t0_ns,
+                stats=self.stats)
+            t_parse = time.monotonic()
             self.stats["captures"] += 1
             self.stats["events"] += len(events)
-            # EWMA of per-cycle dead time (setup + stop + parse) so the
-            # next gap/duration can compensate for it
-            dead = max(0.0, (time.monotonic() - t0) - window_s)
-            self._dead_s = (dead if self._dead_s <= 0
-                            else 0.5 * self._dead_s + 0.5 * dead)
-            self.stats["dead_ms"] = round(self._dead_s * 1000, 1)
+            if any(e.hlo_op for e in events):
+                self.stats["span_windows"] += 1
             self._observe(events, window_s)
             if events:
                 self.sink(events)
+            t_end = time.monotonic()
+            # where the last cycle's dead time went
+            self.stats["stop_ms"] = round((t_stop - window_t0 - window_s)
+                                          * 1000, 1)
+            self.stats["parse_ms"] = round((t_parse - t_stop) * 1000, 1)
+            self.stats["sink_ms"] = round((t_end - t_parse) * 1000, 1)
+            # EWMA of per-cycle dead time (setup + stop + parse + sink)
+            # so the next gap/duration can compensate for it
+            dead = max(0.0, (t_end - t0) - window_s)
+            self._dead_s = (dead if self._dead_s <= 0
+                            else 0.5 * self._dead_s + 0.5 * dead)
+            if window_s > 0:
+                # up at once, down by halves: an estimate that lags a
+                # heavier window overruns the busy budget
+                rate = dead / window_s
+                self._dead_per_s = (rate if rate > self._dead_per_s
+                                    else 0.5 * self._dead_per_s + 0.5 * rate)
+            self.stats["dead_ms"] = round(self._dead_s * 1000, 1)
             return events
         finally:
             _PROFILER_SESSION_LOCK.release()
-            shutil.rmtree(tmpdir, ignore_errors=True)
 
 
 class MemorySource:
@@ -267,20 +342,10 @@ class MemorySource:
     def _devices(self) -> list:
         if self._devices_fn is not None:
             return self._devices_fn()
-        import sys
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return []
-        try:
-            from jax._src import xla_bridge
-            if not xla_bridge.backends_are_initialized():
-                return []  # never steal the TPU from a non-JAX process
-        except Exception:
-            pass
-        try:
-            return jax.devices()
-        except Exception:
-            return []
+        if not jax_backends_initialized():
+            return []  # never take the TPU before the job does
+        import jax
+        return jax.devices()
 
     def poll_once(self) -> list[dict]:
         samples = []
@@ -391,16 +456,12 @@ class HooksSource:
         self._cb = None
 
     def start(self) -> "HooksSource":
-        if self._registered:
-            return self  # re-entry would leak an unremovable listener
-        import sys
-        jax = sys.modules.get("jax")
-        if jax is None:
+        """Register the listener once the job has imported JAX. Safe to
+        call again and again (the probe's capture loop does, because
+        deepflow-run attaches before the job imports JAX)."""
+        if self._registered or not jax_imported():
             return self
-        try:
-            from jax._src import monitoring
-        except ImportError:
-            return self
+        from jax._src import monitoring
 
         def on_duration(name: str, secs: float, **kw) -> None:
             self.stats["events"] += 1
@@ -423,19 +484,11 @@ class HooksSource:
 
     def stop(self) -> None:
         """Unregister the listener so a restarted probe never double-reports."""
-        if not self._registered or self._cb is None:
+        if not self._registered:
             return
         self._registered = False
-        try:
-            from jax._src import monitoring
-            monitoring.unregister_event_duration_listener(self._cb)
-        except (ImportError, AttributeError, ValueError):
-            # older jax: fall back to removing from the listener list directly
-            try:
-                from jax._src import monitoring
-                monitoring._event_duration_secs_listeners.remove(self._cb)
-            except Exception:
-                pass
+        from jax._src import monitoring
+        monitoring.unregister_event_duration_listener(self._cb)
         self._cb = None
 
 

@@ -140,20 +140,32 @@ def parse_xspace(data: bytes) -> list[XPlaneView]:
 
 
 def extract_device_spans(planes: list[XPlaneView],
-                         capture_start_ns: int = 0) -> list[TpuSpanEvent]:
+                         capture_start_ns: int = 0,
+                         stats: dict | None = None) -> list[TpuSpanEvent]:
     """Per-HLO device spans from all /device:TPU:* planes.
 
     Timestamps: device events carry ps offsets relative to the capture
     session; we emit capture_start_ns + offset so rows line up with
     wall-clock host telemetry (close enough for flame/time-series use).
+
+    `stats` (optional) counts what would otherwise vanish in silence:
+    `unknown_device_planes` (a /device: plane whose name the parser does
+    not know) and `planes_without_ops` (a TPU plane with no "XLA Ops"
+    line — a libtpu that renamed its lines).
     """
-    out: list[TpuSpanEvent] = []
+    if stats is None:
+        stats = {}
+    device_planes = []   # (plane, device_id, core_id, module launches)
     for plane in planes:
         m = _DEVICE_RE.match(plane.name)
         if not m:
+            if plane.name.startswith("/device:TPU"):
+                stats["unknown_device_planes"] = \
+                    stats.get("unknown_device_planes", 0) + 1
             continue
-        device_id = int(m.group(1))
-        core_id = int(m.group(2) or m.group(3) or 0)
+        if not any(line.name == "XLA Ops" for line in plane.lines):
+            stats["planes_without_ops"] = \
+                stats.get("planes_without_ops", 0) + 1
         # module launches: (start_ps, end_ps, run_id, module, program_id)
         modules = []
         for line in plane.lines:
@@ -165,7 +177,13 @@ def extract_device_spans(planes: list[XPlaneView],
                 modules.append((ev.offset_ps, ev.offset_ps + ev.duration_ps,
                                 run_id, mod_name, program_id))
         modules.sort()
+        device_planes.append((plane, int(m.group(1)),
+                              int(m.group(2) or m.group(3) or 0), modules))
+    device_planes.sort(key=lambda p: (p[1], p[2]))
+    _align_run_ids([mods for _, _, _, mods in device_planes])
 
+    out: list[TpuSpanEvent] = []
+    for plane, device_id, core_id, modules in device_planes:
         def owning_module(off_ps: int):
             for ms, me, rid, name, prog in modules:
                 if ms <= off_ps < me:
@@ -173,7 +191,7 @@ def extract_device_spans(planes: list[XPlaneView],
             return 0, "", 0
 
         for line in plane.lines:
-            if line.name not in ("XLA Ops",):
+            if line.name != "XLA Ops":
                 continue
             for ev in line.events:
                 dur_ps = int(ev.stats.get("device_duration_ps",
@@ -186,7 +204,7 @@ def extract_device_spans(planes: list[XPlaneView],
                 bytes_acc = int(ev.stats.get("bytes_accessed", 0) or 0)
                 out.append(TpuSpanEvent(
                     start_ns=capture_start_ns + off_ps // 1000,
-                    duration_ns=max(1, dur_ps // 1000),
+                    duration_ns=max(1, (dur_ps + 500) // 1000),
                     device_id=device_id,
                     chip_id=device_id,  # 1 core/chip on v5e; refined by topology
                     core_id=core_id,
@@ -221,13 +239,44 @@ def extract_device_spans(planes: list[XPlaneView],
     return out
 
 
+def _align_run_ids(per_plane: list[list[tuple]]) -> None:
+    """run_id on a TPU plane counts that device's executions: a device
+    that ran more programs (init, input placement on device 0) numbers
+    the same SPMD launch differently, and stitching by run_id split each
+    collective into groups of 3 and 1 on a 2x2 v5e. Each launch takes
+    the run_id of the first plane's launch of the same program that it
+    overlaps in time (all devices run an SPMD step together); a launch
+    that overlaps none keeps its own. Edits the lists in place."""
+    if len(per_plane) < 2:
+        return
+    ref: dict[int, list[tuple]] = {}
+    for launch in per_plane[0]:
+        ref.setdefault(launch[4], []).append(launch)
+    for modules in per_plane[1:]:
+        nxt: dict[int, int] = {}   # program -> first ref launch not past
+        for i, (ms, me, rid, name, prog) in enumerate(modules):
+            cands = ref.get(prog, ())
+            j = nxt.get(prog, 0)
+            while j < len(cands) and cands[j][1] <= ms:
+                j += 1     # launches are sorted: this one ended before
+            nxt[prog] = j
+            best, best_ov = None, 0
+            for r in cands[j:j + 2]:
+                ov = min(me, r[1]) - max(ms, r[0])
+                if ov > best_ov:
+                    best, best_ov = r, ov
+            if best is not None:
+                modules[i] = (ms, me, best[2], name, prog)
+
+
 def _module_kind() -> int:
     from deepflow_tpu.proto import pb
     return pb.DEVICE_COMPUTE
 
 
-def parse_xplane_file(path: str, capture_start_ns: int = 0
-                      ) -> list[TpuSpanEvent]:
+def parse_xplane_file(path: str, capture_start_ns: int = 0,
+                      stats: dict | None = None) -> list[TpuSpanEvent]:
     with open(path, "rb") as f:
         data = f.read()
-    return extract_device_spans(parse_xspace(data), capture_start_ns)
+    return extract_device_spans(parse_xspace(data), capture_start_ns, stats)
+

@@ -52,6 +52,32 @@ def test_stitch_groups_by_run_and_op():
     assert ar[0].max_duration_ns - ar[0].min_duration_ns == 70
 
 
+def test_stitch_aligns_per_device_run_ids():
+    """TPU run_ids count each device's own executions: device 0, which
+    also ran init and input placement, numbers the same SPMD steps
+    apart from devices 1-3 (seen on a 2x2 v5e). The parser gives each
+    launch the overlapping device-0 launch's run_id, so groups stay
+    whole; a launch device 0 has no match for keeps its own."""
+    step_ps = 10_000_000
+    devices = {}
+    for dev in range(4):
+        mods = []
+        for s in range(3 if dev else 2):     # devices 1-3: one step more
+            base = s * step_ps + dev * 50_000
+            rid = (40 if dev == 0 else 7) + s
+            ops = [SynthOp("fusion.1", "convolution fusion", base + 10_000,
+                           6_000_000),
+                   SynthOp("all-reduce.3", "all-reduce", base + 6_050_000,
+                           1_200_000, bytes_accessed=4096)]
+            mods.append(SynthModule("jit_train_step(900)", rid, base,
+                                    8_500_000, ops))
+        devices[dev] = mods
+    spans = extract_device_spans(parse_xspace(build_xspace(devices)))
+    groups = stitch(spans)
+    assert sorted((g.run_id, len(g.participants)) for g in groups) == [
+        (9, 3), (40, 4), (41, 4)]
+
+
 def test_step_trace_joins_devices():
     spans = extract_device_spans(
         parse_xspace(synth_spmd_step(n_devices=4, n_steps=1)))

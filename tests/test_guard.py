@@ -95,3 +95,66 @@ def test_guard_limits_retune_via_config_push():
         assert agent.config.guard.max_cpu_pct == 20.0
     finally:
         agent.stop()
+
+
+def _burn(seconds: float) -> None:
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
+        sum(i * i for i in range(1000))
+
+
+def test_in_process_guard_ignores_the_jobs_threads():
+    """Attached inside a job, the guard holds only the agent's df-*
+    threads to its CPU limit: the job's own compute must not pause the
+    profilers, and process RSS (the job's) is not the agent's: memory is
+    what the agent's sender buffers."""
+    import threading
+    agent = make_agent()
+    try:
+        g = Guard(agent, max_cpu_pct=10.0, max_mem_mb=16,
+                  in_process=True)
+        g.check(now=100.0)
+        _burn(0.3)                       # the job's main thread
+        g.check(now=101.0)
+        assert g.cpu_pct < 10.0 and g.rss_mb < 16
+        assert not g.degraded
+
+        burned, hold = threading.Event(), threading.Event()
+
+        def busy():
+            _burn(0.3)
+            burned.set()
+            hold.wait(5)             # stay alive until measured
+
+        g.check(now=102.0)
+        th = threading.Thread(target=busy, name="df-test-busy")
+        th.start()
+        try:
+            assert burned.wait(10)
+            g.check(now=103.0)
+            assert g.cpu_pct > 10.0      # an agent thread's CPU counts
+            assert g.degraded
+        finally:
+            hold.set()
+            th.join()
+    finally:
+        agent.stop()
+
+
+def test_in_process_guard_holds_buffered_bytes_to_max_mem():
+    """Frames the sender cannot ship pile up in the agent's memory: past
+    max_mem_mb the in-process guard pauses the profilers that make them."""
+    from deepflow_tpu.codec import MessageType
+    agent = make_agent()                 # its server port never answers
+    try:
+        g = Guard(agent, max_cpu_pct=1e6, max_mem_mb=1, in_process=True)
+        g.check(now=100.0)
+        assert not g.degraded
+        for _ in range(3):
+            agent.sender.send(MessageType.PROFILE, b"x" * (512 << 10))
+        assert agent.sender.buffered_bytes() >= 3 * (512 << 10)
+        g.check(now=101.0)
+        assert g.rss_mb >= 1.5 and g.degraded
+        assert g.exception_bitmap & 2    # EXC_MEM_LIMIT
+    finally:
+        agent.stop()

@@ -101,7 +101,7 @@ def test_deadman_wedge_and_recovery():
     release = threading.Event()
 
     def stalls():
-        hb = t.heartbeat("tpuprobe.relay")
+        hb = t.heartbeat("tpuprobe.xplane")
         hb.beat(progress=1)
         done.set()
         release.wait(5.0)   # wedged: no further beats
@@ -113,7 +113,7 @@ def test_deadman_wedge_and_recovery():
     assert d.check_once() == []          # still inside the window
     time.sleep(0.3)
     new = d.check_once()
-    assert [w["stage"] for w in new] == ["tpuprobe.relay"]
+    assert [w["stage"] for w in new] == ["tpuprobe.xplane"]
     w = new[0]
     assert w["stalled_s"] >= 0.2 and w["progress"] == 1
     # the stack snapshot points INTO the stalled thread
@@ -260,15 +260,15 @@ def test_e2e_wedge_detected_named_and_stack_snapshotted(server):
     agent = Agent(cfg).start()
     release = threading.Event()
 
-    def fake_relay():
-        # stands in for a tpuprobe source/relay thread that wedges inside
+    def fake_capture():
+        # stands in for the tpuprobe xplane capture thread that wedges inside
         # capture_once: beats once on entry, then not again until released
-        hb = agent.telemetry.heartbeat("tpuprobe.relay")
+        hb = agent.telemetry.heartbeat("tpuprobe.xplane")
         hb.beat(progress=1)
         release.wait(30.0)
         hb.beat(progress=2)  # recovery beat
 
-    th = threading.Thread(target=fake_relay, name="fake-relay",
+    th = threading.Thread(target=fake_capture, name="fake-capture",
                           daemon=True)
     th.start()
     try:
@@ -282,17 +282,17 @@ def test_e2e_wedge_detected_named_and_stack_snapshotted(server):
                 break
             time.sleep(0.2)
         assert h.get("status") == "degraded", h.get("status")
-        assert "agent:tpuprobe.relay" in h["wedged_stages"]
+        assert "agent:tpuprobe.xplane" in h["wedged_stages"]
         wedges = {w["stage"]: w
                   for w in h["agents_selfmon"]["wedges"]}
-        assert "tpuprobe.relay" in wedges
-        w = wedges["tpuprobe.relay"]
+        assert "tpuprobe.xplane" in wedges
+        w = wedges["tpuprobe.xplane"]
         assert w.get("wedged") == 1.0
         assert w.get("stalled_s", 0) >= 0.6
         # the stack names the wedged frame, not just the stage
-        assert "fake_relay" in w["stack"]
+        assert "fake_capture" in w["stack"]
         assert "release.wait" in w["stack"]
-        hb = h["agents_selfmon"]["heartbeats"]["tpuprobe.relay"]
+        hb = h["agents_selfmon"]["heartbeats"]["tpuprobe.xplane"]
         assert hb["wedged"] == 1.0
 
         # raw rows landed in deepflow_system.deepflow_system too (the

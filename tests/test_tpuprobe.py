@@ -177,6 +177,7 @@ def test_xplane_dead_time_compensation():
 
     src = XPlaneSource(lambda e: None, target_coverage=0.5,
                        steps_per_capture=10)
+    src.max_busy_share = 0.9   # the coverage arithmetic alone
     evs = [TpuSpanEvent(start_ns=i, duration_ns=1, hlo_module="jit_step",
                         run_id=100 + i) for i in range(20)]
     src._observe(evs, 1.0)  # 50ms steps -> 0.5s windows
@@ -193,6 +194,90 @@ def test_xplane_dead_time_compensation():
     assert abs(cov - 0.5) < 0.01, (dur, gap, cov)
 
 
+def test_xplane_busy_time_bounds_window_and_gap():
+    """When the parse outlasts the window (4 chips: ~140k op events a
+    second), coverage gives way: windows shrink until a cycle's predicted
+    dead time fits max_dead_ms, and the gap keeps the capture thread busy
+    at most max_busy_share of the time."""
+    from deepflow_tpu.tpuprobe.events import TpuSpanEvent
+    from deepflow_tpu.tpuprobe.sources import XPlaneSource
+
+    src = XPlaneSource(lambda e: None, target_coverage=0.5,
+                       steps_per_capture=20)
+    assert (src.max_busy_share, src.max_dead_ms) == (0.2, 1500)
+    evs = [TpuSpanEvent(start_ns=i, duration_ns=1, hlo_module="jit_step",
+                        run_id=100 + i) for i in range(32)]
+    src._observe(evs, 1.0)               # 31.25 ms steps: 0.625 s windows
+    src._dead_s, src._dead_per_s = 2.0, 8.0
+    dur, gap = src._next_duration_s(), src._next_gap_s()
+    assert dur == src.min_duration_ms / 1000  # 1.5 s / 8 < the floor
+    dead = 8.0 * dur
+    assert dead / (dur + dead + gap) <= 0.2 + 1e-9, (dur, gap)
+    # a cheap parse leaves the coverage target in charge
+    src._dead_s, src._dead_per_s = 0.05, 0.1
+    dur, gap = src._next_duration_s(), src._next_gap_s()
+    assert abs(dur - 0.625) < 1e-6 and abs(gap - 0.575) < 1e-6
+
+
+def test_xplane_first_window_shrinks_with_devices_and_calibration_carries():
+    from deepflow_tpu.tpuprobe.sources import XPlaneSource
+
+    src = XPlaneSource(lambda e: None, duration_ms=1000)
+    assert src._next_duration_s() == 1.0
+    src._n_devices = 4   # read from jax.local_device_count() at capture
+    assert src._next_duration_s() == 0.25
+    src._step_time_s, src._dead_s, src._dead_per_s = 0.031, 1.2, 6.0
+    again = XPlaneSource(lambda e: None, calibration=src.calibration())
+    assert again._next_duration_s() == src._next_duration_s()
+    assert again._next_gap_s() == src._next_gap_s()
+
+
+def test_probe_stop_keeps_calibration_for_the_next_probe():
+    from types import SimpleNamespace
+
+    from deepflow_tpu.agent.config import AgentConfig
+    from deepflow_tpu.tpuprobe.probe import TpuProbe
+    from deepflow_tpu.tpuprobe.sources import XPlaneSource
+
+    cfg = AgentConfig()
+    cfg.tpuprobe.memory_poll_s = 0
+    agent = SimpleNamespace(config=cfg, process_name="t",
+                            tpuprobe_calibration=None)
+    probe = TpuProbe(agent).start()
+    xsrc = next(s for s in probe.sources if isinstance(s, XPlaneSource))
+    xsrc._step_time_s, xsrc._dead_per_s = 0.031, 6.0
+    probe.stop()
+    assert agent.tpuprobe_calibration["dead_per_s"] == 6.0
+    probe = TpuProbe(agent).start()
+    try:
+        xsrc = next(s for s in probe.sources
+                    if isinstance(s, XPlaneSource))
+        assert xsrc._step_time_s == 0.031
+    finally:
+        probe.stop()
+
+
+def test_xplane_capture_and_user_traces_exclude_each_other(tmp_path):
+    """The probe's session sits where jax.profiler.start_trace looks: a
+    user's trace makes the window count as contended, and after a window
+    the user's start_trace/stop_trace work as before."""
+    import jax
+
+    from deepflow_tpu.tpuprobe.sources import XPlaneSource
+
+    src = XPlaneSource(lambda e: None)
+    jax.profiler.start_trace(str(tmp_path / "user"))
+    try:
+        assert src.capture_once() == []
+        assert src.stats["contended"] == 1
+    finally:
+        jax.profiler.stop_trace()
+    src.capture_once()        # CPU: no TPU plane, so no spans
+    assert src.stats["captures"] == 1 and src.stats["errors"] == 0
+    jax.profiler.start_trace(str(tmp_path / "again"))
+    jax.profiler.stop_trace()
+
+
 def test_xplane_contention_guard():
     """A second source (or user profiling) never collides — the window is
     skipped and counted."""
@@ -207,3 +292,25 @@ def test_xplane_contention_guard():
         assert src.stats["captures"] == 0
     finally:
         S._PROFILER_SESSION_LOCK.release()
+
+
+def test_xplane_counts_planes_it_cannot_read():
+    """A TPU plane without an 'XLA Ops' line, or a /device:TPU plane the
+    parser does not know, shows in the stats instead of vanishing."""
+    from deepflow_tpu.tpuprobe.xplane import (
+        XLineView, XPlaneView, extract_device_spans)
+    planes = [XPlaneView("/device:TPU:0", [XLineView("XLA Modules", 0)]),
+              XPlaneView("/device:TPU_RENAMED:1", []),
+              XPlaneView("/device:CUSTOM:Megascale Trace", []),
+              XPlaneView("/host:CPU", [])]
+    stats = {}
+    assert extract_device_spans(planes, stats=stats) == []
+    assert stats == {"planes_without_ops": 1, "unknown_device_planes": 1}
+
+
+def test_parser_agrees_with_profile_data_on_golden():
+    from examples.smoke_checks import check_against_profile_data
+    check = check_against_profile_data(FIXTURE, device_id=0)
+    assert check["ops"] == 3 and check["events"] == 9
+    assert check["mismatched"] == []
+    assert check["max_rel_diff_ops_from_100ns"] < 1e-4
