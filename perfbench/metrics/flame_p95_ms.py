@@ -1,0 +1,8 @@
+"""p95 of the window's TpuFlame panel queries, timed from when due."""
+
+from perfbench.metrics._stats import p95
+from perfbench.metrics.query_p95_ms import latencies
+
+
+def read(ctx: dict):
+    return p95(latencies(ctx, {"flame"}))
