@@ -1557,8 +1557,8 @@ def main() -> int:
 
     # second headline dimension: step-adaptive continuous capture — the
     # probe sizes its own windows from the observed step cadence targeting
-    # 50% step coverage; we report achieved coverage AND the overhead it
-    # adds to the training loop
+    # 50% step coverage; we report the overhead it adds to the training
+    # loop (coverage is counted from the store by perfbench/)
     span_events = []
     spans_wall = 0.0
     from deepflow_tpu.tpuprobe.sources import XPlaneSource
@@ -1610,11 +1610,6 @@ def main() -> int:
             "hlo_spans_per_s": round(hlo_spans_per_s, 1),
             "hlo_spans_captured": len(device_spans),
             "hlo_device_time_ms": round(device_time_ns / 1e6, 1),
-            # coverage over the measured training window itself (the
-            # source's own stat includes its 1s attach delay)
-            "xplane_coverage_pct": (round(
-                100.0 * adaptive.stats["captured_s"] / spans_wall, 1)
-                if spans_wall else 0.0),
             "xplane_captures": adaptive.stats["captures"],
             "xplane_errors": adaptive.stats["errors"],
             "xplane_dead_ms": adaptive.stats["dead_ms"],
@@ -1623,12 +1618,6 @@ def main() -> int:
             "xplane_overhead_pct": (
                 round(max(0.0, (covered_step - base_step) / base_step
                           * 100.0), 3) if cov_times else 0.0),
-            # coverage guard: target - 5 pts, once the step-adaptive
-            # path engaged (device module spans estimated a cadence)
-            "xplane_coverage_below_target": (
-                spans_wall > 0 and adaptive.stats["est_step_ms"] > 0 and
-                100.0 * adaptive.stats["captured_s"] / spans_wall
-                < adaptive.target_coverage * 100.0 - 5.0),
             **cpu_detail,
         },
     }

@@ -44,6 +44,13 @@ class Agent:
             self.telemetry, window_s=sm.deadman_window_s,
             check_interval_s=sm.check_interval_s or None,
             on_wedge=self._on_wedge)
+        # the agent's own spans (probe cycles, the frames they hand the
+        # sender) on the querier's tracer; no table here, so they are
+        # kept in a ring (`recent_spans`, the "spans" ops command)
+        from deepflow_tpu.query import qtrace
+        self.span_ring = qtrace.SpanRing()
+        self.qtracer = qtrace.QueryTracer(
+            self.telemetry, service="deepflow-agent", sink=self.span_ring)
         if self.config.sender.replication > 1:
             from deepflow_tpu.agent.sender import ReplicatedSender
             self.sender = ReplicatedSender(
@@ -55,7 +62,7 @@ class Agent:
                 durable=self.config.sender.durable,
                 ack_window=self.config.sender.ack_window,
                 spool_factory=self._build_spool_factory(),
-                chaos=self._build_chaos())
+                chaos=self._build_chaos(), tracer=self.qtracer)
         else:
             self.sender = UniformSender(
                 self.config.sender.servers, agent_id=self.config.agent_id,
@@ -64,7 +71,7 @@ class Agent:
                 durable=self.config.sender.durable,
                 ack_window=self.config.sender.ack_window,
                 spool=self._build_spool(),
-                chaos=self._build_chaos())
+                chaos=self._build_chaos(), tracer=self.qtracer)
         self.sampler: OnCpuSampler | None = None
         self.memprofiler = None
         self.extprofilers: list = []
@@ -480,6 +487,11 @@ class Agent:
         JSON, not protobuf; see tpuprobe/stepmetrics.py)."""
         return self.sender.send(MessageType.STEP_METRICS, payload)
 
+    def recent_spans(self) -> list[dict]:
+        """The agent's last finished spans, oldest first."""
+        self.qtracer.flush()
+        return self.span_ring.snapshot()
+
     # -- self-telemetry (reference: agent/src/utils/stats.rs -> dfstats) -----
 
     def _on_wedge(self, verdict: dict) -> None:
@@ -545,8 +557,10 @@ class Agent:
             metric("agent.clock", {
                 "offset_ms": sync.clock_offset_ns / 1e6,
                 "ntp_rtt_ms": sync.ntp_rtt_ns / 1e6})
-        # the self-telemetry spine: hop ledger, stage heartbeats, wedge
-        # verdicts — all ride the same DFSTATS batch into deepflow_system
+        # the self-telemetry spine: hop ledger, stage heartbeats, phase
+        # durations, wedge verdicts — all ride the same DFSTATS batch
+        # into deepflow_system (the span ring's ledger settles first)
+        self.qtracer.flush()
         for name, tags, values in self.telemetry.stats_metrics():
             metric(name, values, extra_tags=tags)
         self.sender.send(MessageType.DFSTATS, batch.SerializeToString())

@@ -36,6 +36,7 @@ class CommandRegistry:
             "queue-tap": self._queue_tap,
             "flows": self._flows,
             "profilers": self._profilers,
+            "spans": self._spans,
             "upgrade": self._upgrade,
             "pcap-capture": self._pcap_capture,
         }
@@ -116,6 +117,12 @@ class CommandRegistry:
             out[f"extprof-{ep.pid}"] = {"samples": ep.stats.samples,
                                         "lost": ep.lost}
         return out
+
+    def _spans(self, args):
+        """The agent's last N finished spans (probe cycles and the
+        frames they sent), newest last. args: [n]"""
+        n = int(args[0]) if args else 64
+        return {"spans": self.agent.recent_spans()[-n:] if n > 0 else []}
 
     def _pcap_capture(self, args):
         """On-demand raw capture shipped to the server (reference: pcap

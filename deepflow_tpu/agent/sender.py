@@ -58,6 +58,7 @@ import time
 from deepflow_tpu.codec import (
     SEQ_EXT_FMT, FrameDecodeError, FrameHeader, MessageType, StreamDecoder,
     encode_frame, encode_seq_base, priority_of)
+from deepflow_tpu.query import qtrace
 
 log = logging.getLogger("df.sender")
 
@@ -68,9 +69,11 @@ class _Frame:
     """One frame's transit state. ``needs_account`` flips False at the
     first successful write so retransmits never double-count. ``seq``
     stays None until the frame first reaches the wire or the spool —
-    shed/dropped frames never own one."""
+    shed/dropped frames never own one. ``trace`` is (trace context, wall
+    ns at send) for a frame sent under an open trace, else None."""
 
-    __slots__ = ("msg_type", "payload", "seq", "enq_ns", "needs_account")
+    __slots__ = ("msg_type", "payload", "seq", "enq_ns", "needs_account",
+                 "trace")
 
     def __init__(self, msg_type: MessageType, payload: bytes,
                  seq: int | None, enq_ns: int | None,
@@ -80,6 +83,7 @@ class _Frame:
         self.seq = seq
         self.enq_ns = enq_ns
         self.needs_account = needs_account
+        self.trace = None
 
 
 class UniformSender:
@@ -93,10 +97,13 @@ class UniformSender:
                  org_id: int = 0, team_id: int = 0, queue_size: int = 8192,
                  connect_timeout: float = 3.0, telemetry=None,
                  spool=None, ack_window: int = 1024,
-                 durable: bool = True, chaos=None) -> None:
+                 durable: bool = True, chaos=None, tracer=None) -> None:
         if not servers:
             raise ValueError("need at least one server address")
         from deepflow_tpu.agent.config import _parse_addr
+        # a frame sent under an open trace (the probe's capture cycle)
+        # becomes a send.frame span of it once written (query/qtrace.py)
+        self._tracer = tracer
         self.servers = [_parse_addr(s) if isinstance(s, str) else tuple(s)
                         for s in servers]
         self.agent_id = agent_id
@@ -204,6 +211,10 @@ class UniformSender:
         # burns one (a burned seq is a permanent gap that stalls the
         # server's contiguous watermark — and with it every ack)
         f = _Frame(msg_type, payload, None, time.monotonic_ns())
+        if self._tracer is not None:
+            ctx = qtrace.ctx_for_wire()
+            if ctx is not None:
+                f.trace = (ctx, time.time_ns())
         try:
             self._q.put_nowait(f)
             return True
@@ -545,6 +556,12 @@ class UniformSender:
                 else:
                     self._hop.account(delivered=1)
                 f.needs_account = False
+                if f.trace is not None:
+                    ctx, sent_ns = f.trace
+                    self._tracer.record(
+                        ctx, "send.frame", sent_ns, time.time_ns(),
+                        agent_id=self.agent_id, seq=f.seq,
+                        msg_type=f.msg_type.name, bytes=len(frame))
             if self.durable:
                 self._unacked[f.seq] = f
                 self._cap_unacked()
@@ -646,7 +663,7 @@ class ReplicatedSender:
                  agent_id: int = 0, org_id: int = 0, team_id: int = 0,
                  queue_size: int = 8192, connect_timeout: float = 3.0,
                  telemetry=None, spool_factory=None, ack_window: int = 1024,
-                 durable: bool = True, chaos=None) -> None:
+                 durable: bool = True, chaos=None, tracer=None) -> None:
         if not servers:
             raise ValueError("need at least one server address")
         from deepflow_tpu.agent.config import _parse_addr
@@ -659,7 +676,7 @@ class ReplicatedSender:
         self._kw = dict(queue_size=queue_size,
                         connect_timeout=connect_timeout,
                         telemetry=telemetry, ack_window=ack_window,
-                        durable=durable, chaos=chaos)
+                        durable=durable, chaos=chaos, tracer=tracer)
         # spool_factory(dest_key) -> Spool | None: one spool dir per
         # destination (their seq spaces are unrelated; sharing a spool
         # would interleave them and break trim/replay watermarks)

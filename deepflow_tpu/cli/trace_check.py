@@ -124,7 +124,7 @@ def main() -> int:
             seed.db.table("deepflow_system.query_trace"),
             "SELECT trace_id, span_id, parent_span_id, name FROM t")
         tids = {v[0] for v in res.values
-                if v[2] == "" and v[3] == "query"}
+                if v[2] == "" and v[3] == "http.request"}
         if len(tids) != 1:
             _fail(f"expected exactly one root trace, got {len(tids)}")
         tid = tids.pop()
@@ -148,7 +148,8 @@ def main() -> int:
         spans = tr["batches"][0]["spans"]
         names = {sp["operationName"] for sp in spans}
         services = {sp["serviceName"] for sp in spans}
-        need = {"query", "scatter", "shard.call", "shard.exec", "merge"}
+        need = {"http.request", "query", "scatter", "shard.call",
+                "shard.exec", "merge"}
         if not need <= names:
             _fail(f"Tempo trace missing spans: {sorted(need - names)}")
         if not any(n.startswith("prune") for n in names):

@@ -8,7 +8,12 @@ Spans land in the system's OWN ``deepflow_system.query_trace`` table (the
 same self-monitoring channel DFSTATS uses), so the existing Tempo search
 API and flame-graph assembler render the querier's internals exactly
 like any instrumented workload: the observability pipeline observing
-itself.
+itself.  The same tracer times the path a TPU profile window takes
+before anyone queries it — the agent's ``probe.cycle`` (its spans kept
+in a ``SpanRing``), the sender's ``send.frame`` and the server's
+``ingest.frame`` — and each HTTP request to the dashboard routes
+(``http.request``), on the ``time.time_ns()`` clock that device spans
+are stored on (docs/QUERYTRACE.md).
 
 Design constraints that shaped the module:
 
@@ -41,6 +46,7 @@ threshold, default 250).
 
 from __future__ import annotations
 
+import collections
 import itertools
 import json
 import logging
@@ -229,11 +235,12 @@ class _RootSpan(Span):
         if exc_type is not None:
             self.status = "error"
             self.attrs.setdefault("error", f"{exc_type.__name__}: {exc}")
+        # an end the caller stamped itself stands
         if not self.end_ns:
             self.end_ns = time.time_ns()
             if self.cpu_start_ns:
                 self.cpu_ns = time.thread_time_ns() - self.cpu_start_ns
-            self._buf.spans.append(self)
+        self._buf.spans.append(self)
         _tls.span = self._prev
         _tls.buf = self._prev_buf
         self._buf.tracer._complete(self._buf)
@@ -354,9 +361,16 @@ class QueryTracer:
         """Open the ROOT span of a new trace on this thread.  Use as a
         context manager; on exit the whole trace is accounted+flushed.
         ``capture=True`` (EXPLAIN ANALYZE) keeps spans on the buf for the
-        caller regardless of the sampling verdict."""
+        caller regardless of the sampling verdict.  Inside a trace this
+        tracer already has open on the thread (the querier's
+        ``http.request`` root) the span hangs under it instead, and
+        ``capture`` keeps that whole trace."""
         if not _enabled() and not capture:
             return _NULL_SPAN  # type: ignore[return-value]
+        outer = getattr(_tls, "buf", None)
+        if outer is not None and outer.tracer is self and not trace_id:
+            outer.capture = outer.capture or capture
+            return Span(outer, name, current_span_id(), attrs)
         if trace_id:
             tid = trace_id
         else:
@@ -385,6 +399,17 @@ class QueryTracer:
         root = _RootSpan(buf, name, str(ctx.get("sid", "")), attrs)
         buf.root = root
         return root
+
+    def record(self, ctx, name: str, start_ns: int, end_ns: int,
+               **attrs) -> None:
+        """A finished span with caller-stamped bounds, joined to a trace
+        that another thread propagated (``ctx_for_wire``): the sender's
+        ``send.frame`` and the probe's ``probe.gap``."""
+        root = self.adopt(ctx, name, **attrs)
+        if isinstance(root, _RootSpan):
+            root.start_ns, root.end_ns = start_ns, end_ns
+            with root:
+                pass
 
     def _complete(self, buf: _TraceBuf) -> None:
         """Hand a finished trace over.  Runs on the query thread at root
@@ -591,6 +616,33 @@ def span(name: str, **attrs):
     return Span(buf, name, pid, attrs)
 
 
+def record_span(name: str, start_ns: int, end_ns: int, **attrs):
+    """A finished child of the current span whose bounds the caller took
+    itself (time.time_ns() clock), for phases stamped before their trace
+    opened or measured on another clock; no-op when no trace is
+    active."""
+    sp = span(name, **attrs)
+    if isinstance(sp, Span):
+        sp.start_ns, sp.end_ns, sp.cpu_start_ns = start_ns, end_ns, 0
+        sp._buf.add(sp)
+    return sp
+
+
+class SpanRing:
+    """A tracer sink that keeps the last ``size`` finished span dicts in
+    memory: where a process has no table to write (the agent), its spans
+    are read back from here."""
+
+    def __init__(self, size: int = 4096) -> None:
+        self._spans: collections.deque = collections.deque(maxlen=size)
+
+    def __call__(self, spans: list[dict]) -> None:
+        self._spans.extend(spans)
+
+    def snapshot(self) -> list[dict]:
+        return list(self._spans)
+
+
 def annotate(**attrs) -> None:
     cur = getattr(_tls, "span", None)
     if isinstance(cur, Span):
@@ -627,8 +679,13 @@ def ctx_for_wire() -> dict | None:
         return None
     if buf.sampled is None:
         # fan-out forces the head verdict now so every shard of this
-        # trace lives or dies together (local traces decide at drain)
-        buf.sampled = _head_keep(buf.trace_id, _sample_n())
+        # trace lives or dies together (local traces decide at drain);
+        # a trace already past the slow threshold is kept, as the tail
+        # rule would keep it at drain
+        root = buf.root
+        buf.sampled = (_head_keep(buf.trace_id, _sample_n())
+                       or (root is not None and time.time_ns()
+                           - root.start_ns >= _slow_ns()))
     return {"tid": buf.trace_id, "sid": current_span_id(),
             "sampled": buf.sampled}
 

@@ -21,6 +21,7 @@ from deepflow_tpu.codec import FrameHeader, MessageType
 from deepflow_tpu.native import (
     ArenaStrings, IP_FALLBACK, IP_SRC_EMPTY, IP_DST_EMPTY)
 from deepflow_tpu.proto import pb
+from deepflow_tpu.query import qtrace
 from deepflow_tpu.store.db import Database
 from deepflow_tpu.store.schema import (
     L4_PROTOS, L7_PROTOS, PROFILE_EVENT_TYPES, RESPONSE_STATUS,
@@ -118,6 +119,11 @@ class Decoder:
 
     MSG_TYPE: MessageType
 
+    # one ingest.frame span per decoded frame (query/qtrace.py), for the
+    # probe's low-rate frames only: a span a frame on the bulk flow and
+    # metrics paths would cost more than the decode
+    TRACE_FRAMES = False
+
     WORKERS = 1  # ingest parallelism (reference: per-type decoder queues
     # with N workers, flow_metrics.go:55-61). FlowLogDecoder overrides
     # via DF_INGEST_WORKERS: its native columnar parse (pbcols.cpp)
@@ -131,7 +137,7 @@ class Decoder:
                  workers: int | None = None, resources=None,
                  trace_trees=None, telemetry=None, dedup=None,
                  seq_tracker=None, ring=None, durability=None,
-                 qos_sampler=None) -> None:
+                 qos_sampler=None, tracer=None) -> None:
         # q: one Queue, or a LIST of lane queues (receiver connection
         # affinity — see Receiver.register(lanes=)). With N lanes and N
         # workers each worker owns one lane exclusively, so one hot
@@ -166,6 +172,7 @@ class Decoder:
         # sampling of bulk flow/L7 records when the frame's tenant is
         # under pressure (only FlowLogDecoder consults it)
         self.qos_sampler = qos_sampler
+        self.tracer = tracer if self.TRACE_FRAMES else None
         self.workers = workers if workers is not None else self.WORKERS
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
@@ -206,28 +213,32 @@ class Decoder:
         # ACCEPTED (and, on the durable path, acked) — exiting with a
         # non-empty queue would lose them on every restart even though
         # the agent was told not to retransmit
-        drained = []
+        drained, stamps = [], []
         for lane_q in self.queues:
             while True:
                 try:
-                    drained.extend(self._unwrap(lane_q.get_nowait()))
+                    drained.extend(self._unwrap(lane_q.get_nowait(), stamps))
                 except queue.Empty:
                     break
         if drained:
-            self._handle_items(drained)
+            self._handle_items(drained, stamps)
 
-    def _handle_items(self, items: list) -> None:
+    def _handle_items(self, items: list, stamps: list | None = None) -> None:
         """Decode+write a list of (header, payload); shared by the worker
-        loop and the shutdown drain."""
+        loop and the shutdown drain. ``stamps`` holds each frame's
+        (received, dequeued) wall ns where frames are traced."""
         batches = rows = errors = dups = 0
         t0 = time.perf_counter_ns()
-        for header, payload in items:
+        for i, (header, payload) in enumerate(items):
             if (self.dedup is not None and header.seq is not None
                     and self.dedup.seen(header.agent_id, header.seq)):
                 dups += 1
                 continue
             try:
-                rows += self.handle(header, payload)
+                if stamps:
+                    rows += self._handle_traced(header, payload, *stamps[i])
+                else:
+                    rows += self.handle(header, payload)
                 batches += 1
             except Exception:
                 errors += 1
@@ -257,19 +268,40 @@ class Decoder:
             self.stats["dups"] += dups
             self.stats["handle_ns"] += dt
 
+    def _handle_traced(self, header: FrameHeader, payload: bytes,
+                       recv_ns: int, deq_ns: int) -> int:
+        """``handle`` under an ingest.frame span: received by the
+        receiver -> dequeued here -> rows appended. It joins the agent's
+        send.frame on (agent_id, seq)."""
+        with self.tracer.start_trace(
+                "ingest.frame", agent_id=header.agent_id, seq=header.seq,
+                msg_type=self.MSG_TYPE.name) as root:
+            if isinstance(root, qtrace.Span):
+                root.start_ns = recv_ns
+            qtrace.record_span("ingest.queue", recv_ns, deq_ns)
+            with qtrace.span("ingest.write") as write:
+                n = self.handle(header, payload)
+                write.annotate(rows=n)
+        return n
+
     DRAIN_FRAMES = 64  # max frames one worker consumes per wakeup
 
-    def _unwrap(self, item) -> list:
+    def _unwrap(self, item, stamps: list | None = None) -> list:
         """Accept both the receiver's ``(enqueue_ns, frames)`` shape and a
         bare frame list (tests feed decoder queues directly); account the
-        dequeue on the ledger + queue-wait histogram."""
+        dequeue on the ledger + queue-wait histogram. With a tracer, add
+        each frame's (received, dequeued) wall ns to ``stamps``."""
         if isinstance(item, tuple):
             enq_ns, frames = item
-            self._hop.account(emitted=len(frames),
-                              wait_ns=time.monotonic_ns() - enq_ns)
+            wait_ns = time.monotonic_ns() - enq_ns
+            self._hop.account(emitted=len(frames), wait_ns=wait_ns)
         else:
             frames = item
+            wait_ns = 0
             self._hop.account(emitted=len(frames))
+        if self.tracer is not None and stamps is not None:
+            now = time.time_ns()
+            stamps.extend([(now - wait_ns, now)] * len(frames))
         return frames
 
     def _run(self, worker_idx: int = 0) -> None:
@@ -282,8 +314,9 @@ class Decoder:
         handled = 0
         while not self._stop.is_set():
             hb.beat(progress=handled)
+            stamps: list = []
             try:
-                items = self._unwrap(lane_q.get(timeout=0.2))
+                items = self._unwrap(lane_q.get(timeout=0.2), stamps)
             except queue.Empty:
                 continue
             # greedy drain: the receiver enqueues LISTS of frames (one per
@@ -292,11 +325,11 @@ class Decoder:
             # siblings under WORKERS > 1
             while len(items) < self.DRAIN_FRAMES:
                 try:
-                    items = items + self._unwrap(lane_q.get_nowait())
+                    items = items + self._unwrap(lane_q.get_nowait(), stamps)
                 except queue.Empty:
                     break
             handled += len(items)
-            self._handle_items(items)
+            self._handle_items(items, stamps)
 
     def handle(self, header: FrameHeader, payload: bytes) -> int:
         raise NotImplementedError
@@ -402,6 +435,7 @@ class TpuSpanDecoder(Decoder):
     (golden parity test)."""
 
     MSG_TYPE = MessageType.TPU_SPAN
+    TRACE_FRAMES = True
 
     def __init__(self, *a, **kw) -> None:
         super().__init__(*a, **kw)
@@ -546,6 +580,7 @@ class StepMetricsDecoder(Decoder):
     dropped/decode_error like any other bad payload."""
 
     MSG_TYPE = MessageType.STEP_METRICS
+    TRACE_FRAMES = True
 
     def handle(self, header: FrameHeader, payload: bytes) -> int:
         from deepflow_tpu.tpuprobe.stepmetrics import decode_step_payload

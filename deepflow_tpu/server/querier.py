@@ -25,6 +25,10 @@ log = logging.getLogger("df.querier")
 
 # dd-trace agent API paths: accepted on POST and (dd default) PUT
 _DD_TRACE_PATHS = ("/v0.3/traces", "/v0.4/traces")
+# POST routes whose requests open an http.request root span: the
+# dashboard's DF-SQL, flame and HBM panels
+_TRACED_ROUTES = ("/v1/query", "/v1/profile/TpuFlame",
+                  "/v1/profile/TpuMemory")
 
 
 class AuthError(Exception):
@@ -274,12 +278,9 @@ class QuerierAPI:
     def _parse_span(t0: int, c0: int, t1: int, c1: int) -> None:
         """Re-attribute a parse that happened just before the trace
         opened (statement routing needs the AST first)."""
-        sp = qtrace.span("parse")
+        sp = qtrace.record_span("parse", t0, t1)
         if isinstance(sp, qtrace.Span):
-            sp.start_ns, sp.cpu_start_ns = t0, c0
-            sp.end_ns = t1
             sp.cpu_ns = c1 - c0
-            sp._buf.add(sp)
 
     def _run_select(self, body: dict, select: qsql.Select, sql_text: str,
                     db_name: str) -> dict:
@@ -549,19 +550,23 @@ class QuerierAPI:
             "GROUP BY hlo_module, hlo_category, hlo_op")
         fed = self._fed()
         info = None
-        if fed is not None:
-            # an exact push-down case: Sum partials merge shard-side ids
-            # never travel (group keys are decoded strings)
-            res, info = fed.sql_query(table, qsql.parse(sql_text),
-                                      sql_text)
-        else:
-            res = qengine.execute(table, sql_text)
+        with qtrace.span("execute", path="tpu_flame",
+                         federated=fed is not None) as ex:
+            if fed is not None:
+                # an exact push-down case: Sum partials merge shard-side
+                # ids never travel (group keys are decoded strings)
+                res, info = fed.sql_query(table, qsql.parse(sql_text),
+                                          sql_text)
+            else:
+                res = qengine.execute(table, sql_text)
+            ex.annotate(rows=len(res.values))
         from deepflow_tpu.query.flamegraph import build_flame_tree
         stacks, values = [], []
         for mod, cat, op, d in res.values:
             stacks.append(";".join(x for x in (mod, cat or "other", op) if x))
             values.append(int(d))
-        out = {"result": build_flame_tree(stacks, values).to_dict()}
+        with qtrace.span("flame.build"):
+            out = {"result": build_flame_tree(stacks, values).to_dict()}
         if info is not None:
             out["federation"] = info
         return out
@@ -581,10 +586,12 @@ class QuerierAPI:
             where.append(f"time < {int(body['time_end'])}")
         if body.get("device_id") is not None:
             where.append(f"device_id = {int(body['device_id'])}")
-        res = qengine.execute(
-            mem, "SELECT time, device_id, bytes_in_use, peak_bytes_in_use, "
-                 "bytes_limit, largest_free_block FROM t "
-                 f"WHERE {' AND '.join(where)} ORDER BY time")
+        with qtrace.span("execute", path="tpu_memory.timeline") as ex:
+            res = qengine.execute(
+                mem, "SELECT time, device_id, bytes_in_use, "
+                     "peak_bytes_in_use, bytes_limit, largest_free_block "
+                     f"FROM t WHERE {' AND '.join(where)} ORDER BY time")
+            ex.annotate(rows=len(res.values))
         timeline = [
             {"time": int(t), "device_id": int(d), "bytes_in_use": int(b),
              "peak_bytes_in_use": int(p), "bytes_limit": int(lim),
@@ -609,12 +616,14 @@ class QuerierAPI:
         if body.get("time_end"):
             swhere.append(f"time < {int(body['time_end'])}")
         top_n = int(body.get("top", 15))
-        sres = qengine.execute(
-            spans, "SELECT hlo_op, hlo_module, Sum(bytes_accessed) AS b, "
-                   "Sum(duration_ns) AS d, Count() AS n FROM t "
-                   f"WHERE {' AND '.join(swhere)} "
-                   "GROUP BY hlo_op, hlo_module ORDER BY b DESC "
-                   f"LIMIT {top_n}")
+        with qtrace.span("execute", path="tpu_memory.top_ops") as ex:
+            sres = qengine.execute(
+                spans, "SELECT hlo_op, hlo_module, Sum(bytes_accessed) AS b, "
+                       "Sum(duration_ns) AS d, Count() AS n FROM t "
+                       f"WHERE {' AND '.join(swhere)} "
+                       "GROUP BY hlo_op, hlo_module ORDER BY b DESC "
+                       f"LIMIT {top_n}")
+            ex.annotate(rows=len(sres.values))
         top_ops = [
             {"hlo_op": op, "hlo_module": mod, "bytes_accessed": int(b),
              "duration_ns": int(d), "count": int(n),
@@ -1900,6 +1909,7 @@ class QuerierHTTP:
                 log.debug(fmt, *args)
 
             def _send(self, code: int, obj: dict) -> None:
+                qtrace.annotate(code=code)  # on the http.request root
                 data = json.dumps(obj).encode()
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
@@ -2040,6 +2050,16 @@ class QuerierHTTP:
                     self._send(400, {"error": str(e)})
 
             def do_POST(self) -> None:
+                from urllib.parse import urlparse
+                route = urlparse(self.path).path.rstrip("/")
+                if route not in _TRACED_ROUTES:
+                    return self._post()
+                # body read -> response written, with the route's own
+                # trace (the query tree) hanging under it
+                with api.qtracer.start_trace("http.request", route=route):
+                    self._post()
+
+            def _post(self) -> None:
                 from urllib.parse import parse_qsl, urlparse
                 try:
                     parsed = urlparse(self.path)

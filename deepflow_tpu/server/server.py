@@ -552,7 +552,8 @@ class Server:
                     telemetry=self.telemetry, dedup=self.dedup,
                     seq_tracker=self.receiver.seq_tracker,
                     ring=self._current_ring,
-                    durability=self.durability, **kw)
+                    durability=self.durability, tracer=self.api.qtracer,
+                    **kw)
             d.MSG_TYPE = mtype  # FlowLogDecoder serves two types
             self.decoders.append(d.start())
         if self.storage:

@@ -35,7 +35,6 @@ class TpuProbe:
             from deepflow_tpu.telemetry import Telemetry
             telemetry = Telemetry("agent", enabled=False)
         self.telemetry = telemetry
-        self._hop = telemetry.hop("tpuprobe")
         # per-step rollups ride the span sink; their frames get their own
         # hop so a steps-path loss never hides inside the span ledger
         self.stepagg: StepAggregator | None = None
@@ -59,7 +58,8 @@ class TpuProbe:
                 calibration=getattr(self.agent, "tpuprobe_calibration",
                                     None),
                 telemetry=self.telemetry,
-                on_cycle=hooks.start).start())
+                on_cycle=hooks.start,
+                tracer=getattr(self.agent, "qtracer", None)).start())
             self.sources.append(hooks)
             if self.cfg.memory_poll_s > 0:
                 self.sources.append(MemorySource(
@@ -97,7 +97,6 @@ class TpuProbe:
         with self._lock:
             self.stats["spans_sent"] += len(events)
             self.stats["batches"] += 1
-        self._hop.account(emitted=1, delivered=1)
         self.agent.send_tpu_spans(batch)
         if self.stepagg:
             self.stepagg.feed(events)
@@ -128,5 +127,4 @@ class TpuProbe:
         with self._lock:
             self.stats["mem_samples_sent"] = \
                 self.stats.get("mem_samples_sent", 0) + len(samples)
-        self._hop.account(emitted=1, delivered=1)
         self.agent.send_tpu_spans(batch)

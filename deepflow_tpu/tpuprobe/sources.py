@@ -16,8 +16,10 @@ import threading
 import time
 
 from deepflow_tpu.proto import pb
+from deepflow_tpu.query import qtrace
 from deepflow_tpu.tpuprobe.events import TpuSpanEvent
-from deepflow_tpu.tpuprobe.xplane import extract_device_spans, parse_xspace
+from deepflow_tpu.tpuprobe.xplane import (
+    device_clock_offsets, extract_device_spans, parse_xspace)
 
 log = logging.getLogger("df.tpuprobe")
 
@@ -72,7 +74,7 @@ class XPlaneSource:
                  max_duration_ms: int = 4000,
                  min_gap_ms: int = 200, calibration: dict | None = None,
                  telemetry=None,
-                 on_cycle=None) -> None:
+                 on_cycle=None, tracer=None) -> None:
         self.sink = sink
         # called at the top of every loop cycle (the probe registers its
         # jax.monitoring hooks from here once the job has imported JAX)
@@ -81,6 +83,15 @@ class XPlaneSource:
             from deepflow_tpu.telemetry import Telemetry
             telemetry = Telemetry("agent", enabled=False)
         self._telemetry = telemetry
+        # each capture cycle is one probe.cycle trace; the agent hands
+        # its own tracer in, a source alone keeps its spans in a ring
+        if tracer is None:
+            tracer = qtrace.QueryTracer(service="deepflow-tpuprobe",
+                                        sink=qtrace.SpanRing())
+        self.tracer = tracer
+        # (trace context, ns) of the last cycle's sink end: the next
+        # cycle closes the probe.gap span that starts there
+        self._gap_from: tuple | None = None
         self.interval_s = interval_s        # fallback cadence (no steps yet)
         self.duration_ms = duration_ms
         self.target_coverage = min(max(target_coverage, 0.05), 0.95)
@@ -99,7 +110,6 @@ class XPlaneSource:
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._step_time_s = 0.0             # estimated from module spans
-        self._captured_s = 0.0
         # per-cycle dead time: start_trace setup + stop_trace + xplane
         # parse. Ignoring it is why coverage sat ~10 pts under target for
         # three rounds (VERDICT r04 weak #3): the real cycle is
@@ -118,16 +128,13 @@ class XPlaneSource:
             self._step_time_s = calibration["step_time_s"]
             self._dead_s = calibration["dead_s"]
             self._dead_per_s = calibration["dead_per_s"]
-        self._started_monotonic = time.monotonic()
         # span_windows: captures that yielded device op spans;
         # planes_without_ops: TPU device planes with no "XLA Ops" line;
         # unknown_device_planes: /device: planes the parser skipped
         self.stats = {"captures": 0, "events": 0, "errors": 0, "skipped": 0,
                       "contended": 0, "span_windows": 0,
                       "planes_without_ops": 0, "unknown_device_planes": 0,
-                      "steps_seen": 0,
-                      "coverage_pct": 0.0, "est_step_ms": 0.0,
-                      "captured_s": 0.0, "dead_ms": 0.0,
+                      "steps_seen": 0, "est_step_ms": 0.0, "dead_ms": 0.0,
                       "stop_ms": 0.0, "parse_ms": 0.0, "sink_ms": 0.0}
 
     def calibration(self) -> dict:
@@ -205,18 +212,25 @@ class XPlaneSource:
                    self.max_duration_ms / 1000.0)
 
     def _next_gap_s(self) -> float:
-        """Gap between windows for the target step coverage. The real
-        cycle is dead + duration + gap, so the measured dead time comes
-        out of the gap budget — but never so far that the dead time
-        exceeds max_busy_share of the cycle."""
+        return self._plan_gap()[0]
+
+    def _plan_gap(self) -> tuple[float, str]:
+        """Gap between windows for the target step coverage, and the
+        bound that set it. The real cycle is dead + duration + gap, so
+        the measured dead time comes out of the gap budget ("target") —
+        but never so far that the dead time exceeds max_busy_share of
+        the cycle ("busy_share"), nor under min_gap_ms ("min_gap");
+        with no cadence yet, interval_s ("fallback")."""
         dur = self._next_duration_s()
         dead = max(self._dead_s, self._dead_per_s * dur)
         busy_gap = dead / self.max_busy_share - dead - dur
         if self._step_time_s <= 0:
             # cadence unknown: conservative fallback
-            return max(self.interval_s, busy_gap)
+            return max((self.interval_s, "fallback"),
+                       (busy_gap, "busy_share"))
         gap = dur * (1.0 / self.target_coverage - 1.0) - self._dead_s
-        return max(gap, busy_gap, self.min_gap_ms / 1000.0)
+        return max((gap, "target"), (busy_gap, "busy_share"),
+                   (self.min_gap_ms / 1000.0, "min_gap"))
 
     def _observe(self, events: list, wall_s: float) -> None:
         """Update the step-cadence estimate from a capture's module spans."""
@@ -230,11 +244,6 @@ class XPlaneSource:
             self._step_time_s = (est if self._step_time_s <= 0 else
                                  0.5 * self._step_time_s + 0.5 * est)
             self.stats["est_step_ms"] = round(self._step_time_s * 1000, 2)
-        self.stats["captured_s"] = round(self._captured_s, 3)
-        elapsed = time.monotonic() - self._started_monotonic
-        if elapsed > 0:
-            self.stats["coverage_pct"] = round(
-                100.0 * self._captured_s / elapsed, 1)
 
     def capture_once(self) -> list[TpuSpanEvent]:
         import jax
@@ -246,8 +255,16 @@ class XPlaneSource:
             return []
         self._n_devices = max(1, jax.local_device_count())
         state = jax_profiler._profile_state
+        # phases are timed on the monotonic clock; spans are stamped on
+        # the time.time_ns() clock of the stored device spans, from t0
         t0_ns = time.time_ns()
-        t0 = time.monotonic()
+        t0 = time.monotonic_ns()
+        if self._gap_from is not None:
+            ctx, gap_start_ns = self._gap_from
+            self._gap_from = None
+            self._telemetry.observe_phase("tpuprobe.cycle", "gap",
+                                          t0_ns - gap_start_ns)
+            self.tracer.record(ctx, "probe.gap", gap_start_ns, t0_ns)
         try:
             # device planes are all we parse: host/python tracers only
             # add overhead to the workload while the window is open
@@ -273,46 +290,79 @@ class XPlaneSource:
             # sleep through the window; workload threads keep running.
             # The covered span is the open-trace wait only — session
             # setup, stop, parse and sink are dead time.
-            window_t0 = time.monotonic()
-            self._stop.wait(self._next_duration_s())
-            window_s = time.monotonic() - window_t0
+            t_live = time.monotonic_ns()
+            planned_s = self._next_duration_s()
+            self._stop.wait(planned_s)
+            t_window = time.monotonic_ns()
+            window_s = (t_window - t_live) / 1e9
             with state.lock:
                 state.profile_session = None
                 xspace = session.stop()
-            t_stop = time.monotonic()
-            self._captured_s += window_s
-            events = extract_device_spans(
-                parse_xspace(xspace), capture_start_ns=t0_ns,
-                stats=self.stats)
-            t_parse = time.monotonic()
+            t_stop = time.monotonic_ns()
+            planes = parse_xspace(xspace)
+            events = extract_device_spans(planes, capture_start_ns=t0_ns,
+                                          stats=self.stats)
+            clock = device_clock_offsets(planes, t0_ns)
+            t_parse = time.monotonic_ns()
             self.stats["captures"] += 1
             self.stats["events"] += len(events)
             if any(e.hlo_op for e in events):
                 self.stats["span_windows"] += 1
             self._observe(events, window_s)
-            if events:
-                self.sink(events)
-            t_end = time.monotonic()
-            # where the last cycle's dead time went
-            self.stats["stop_ms"] = round((t_stop - window_t0 - window_s)
-                                          * 1000, 1)
-            self.stats["parse_ms"] = round((t_parse - t_stop) * 1000, 1)
-            self.stats["sink_ms"] = round((t_end - t_parse) * 1000, 1)
-            # EWMA of per-cycle dead time (setup + stop + parse + sink)
-            # so the next gap/duration can compensate for it
-            dead = max(0.0, (t_end - t0) - window_s)
-            self._dead_s = (dead if self._dead_s <= 0
-                            else 0.5 * self._dead_s + 0.5 * dead)
-            if window_s > 0:
-                # up at once, down by halves: an estimate that lags a
-                # heavier window overruns the busy budget
-                rate = dead / window_s
-                self._dead_per_s = (rate if rate > self._dead_per_s
-                                    else 0.5 * self._dead_per_s + 0.5 * rate)
-            self.stats["dead_ms"] = round(self._dead_s * 1000, 1)
+            # the sink runs under the cycle's trace: the sender joins
+            # the frames it is handed to it
+            with self.tracer.start_trace(
+                    "probe.cycle", events=len(events),
+                    devices=self._n_devices,
+                    planned_window_ms=round(planned_s * 1000, 1)) as cycle:
+                if isinstance(cycle, qtrace.Span):
+                    cycle.start_ns = t0_ns
+                if events:
+                    self.sink(events)
+                t_end = time.monotonic_ns()
+                # where the last cycle's dead time went
+                self.stats["stop_ms"] = round((t_stop - t_window) / 1e6, 1)
+                self.stats["parse_ms"] = round((t_parse - t_stop) / 1e6, 1)
+                self.stats["sink_ms"] = round((t_end - t_parse) / 1e6, 1)
+                # EWMA of per-cycle dead time (setup + stop + parse +
+                # sink) so the next gap/duration can compensate for it
+                dead = max(0.0, (t_end - t0) / 1e9 - window_s)
+                self._dead_s = (dead if self._dead_s <= 0
+                                else 0.5 * self._dead_s + 0.5 * dead)
+                if window_s > 0:
+                    # up at once, down by halves: an estimate that lags a
+                    # heavier window overruns the busy budget
+                    rate = dead / window_s
+                    self._dead_per_s = (
+                        rate if rate > self._dead_per_s
+                        else 0.5 * self._dead_per_s + 0.5 * rate)
+                self.stats["dead_ms"] = round(self._dead_s * 1000, 1)
+                gap_s, bound = self._plan_gap()
+                cycle.annotate(planned_gap_ms=round(gap_s * 1000, 1),
+                               gap_bound=bound)
+                self._record_phases(t0_ns, (
+                    ("open", t0, t_live, {}), ("window", t_live, t_window,
+                                               clock),
+                    ("stop", t_window, t_stop, {}),
+                    ("parse", t_stop, t_parse, {}),
+                    ("sink", t_parse, t_end, {})))
+                if isinstance(cycle, qtrace.Span):
+                    cycle.end_ns = t0_ns + t_end - t0
+                self._gap_from = (qtrace.ctx_for_wire(),
+                                  t0_ns + t_end - t0)
             return events
         finally:
             _PROFILER_SESSION_LOCK.release()
+
+    def _record_phases(self, t0_ns: int, phases) -> None:
+        """The cycle's phases, which tile it: one child span each, on
+        the wall clock from t0_ns, and one DFSTATS phase duration each.
+        Bounds are monotonic ns; the first phase starts at t0_ns."""
+        m0 = phases[0][1]
+        for name, a, b, attrs in phases:
+            self._telemetry.observe_phase("tpuprobe.cycle", name, b - a)
+            qtrace.record_span(f"probe.{name}", t0_ns + a - m0,
+                               t0_ns + b - m0, **attrs)
 
 
 class MemorySource:

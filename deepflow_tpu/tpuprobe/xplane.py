@@ -239,6 +239,30 @@ def extract_device_spans(planes: list[XPlaneView],
     return out
 
 
+def device_clock_offsets(planes: list[XPlaneView],
+                         capture_start_ns: int) -> dict:
+    """How far the capture's own clock sits from capture_start_ns, which
+    extract_device_spans stamps every span from: the earliest device
+    XLine timestamp_ns, and the absolute start (line timestamp + event
+    offset, as ProfileData reads it) of the first "XLA Ops" event, each
+    minus capture_start_ns. Empty without device planes."""
+    line_ts, first_op = [], []
+    for plane in planes:
+        if not _DEVICE_RE.match(plane.name):
+            continue
+        for line in plane.lines:
+            line_ts.append(line.timestamp_ns)
+            if line.name == "XLA Ops" and line.events:
+                first_op.append(line.timestamp_ns + min(
+                    ev.offset_ps for ev in line.events) // 1000)
+    out = {}
+    if line_ts:
+        out["xline_offset_ns"] = min(line_ts) - capture_start_ns
+    if first_op:
+        out["first_op_offset_ns"] = min(first_op) - capture_start_ns
+    return out
+
+
 def _align_run_ids(per_plane: list[list[tuple]]) -> None:
     """run_id on a TPU plane counts that device's executions: a device
     that ran more programs (init, input placement on device 0) numbers

@@ -216,8 +216,7 @@ def smoke(args, cfg) -> int:
         k: xsrc.stats[k] for k in (
             "captures", "events", "errors", "contended", "span_windows",
             "planes_without_ops", "unknown_device_planes", "dead_ms",
-            "stop_ms", "parse_ms", "sink_ms", "est_step_ms",
-            "coverage_pct")}
+            "stop_ms", "parse_ms", "sink_ms", "est_step_ms")}
     report["probe"].update(
         spans_sent=probe.stats["spans_sent"],
         mem_samples_sent=probe.stats.get("mem_samples_sent", 0),

@@ -387,8 +387,9 @@ def test_federated_query_stitches_one_trace(monkeypatch):
         res = engine.execute(
             seed.db.table("deepflow_system.query_trace"),
             "SELECT trace_id, parent_span_id, name FROM t")
+        # the HTTP request is the root; the query tree hangs under it
         tids = {v[0] for v in res.values if v[1] == ""
-                and v[2] == "query"}
+                and v[2] == "http.request"}
         assert len(tids) == 1, "exactly one coordinator root trace"
         tid = tids.pop()
 
@@ -417,13 +418,16 @@ def test_federated_query_stitches_one_trace(monkeypatch):
         spans = tr["batches"][0]["spans"]
         names = {sp["operationName"] for sp in spans}
         services = {sp["serviceName"] for sp in spans}
-        assert {"query", "scatter", "shard.call", "shard.exec",
-                "merge"} <= names
+        assert {"http.request", "query", "scatter", "shard.call",
+                "shard.exec", "merge"} <= names
         assert any(n.startswith("prune") for n in names)
         assert {"deepflow-querier-1", "deepflow-querier-2",
                 "deepflow-querier-3"} <= services
         roots = [sp for sp in spans if sp["parentSpanID"] == ""]
-        assert len(roots) == 1 and roots[0]["operationName"] == "query"
+        assert len(roots) == 1 \
+            and roots[0]["operationName"] == "http.request"
+        query = [sp for sp in spans if sp["operationName"] == "query"]
+        assert [sp["parentSpanID"] for sp in query] == [roots[0]["spanID"]]
 
         # Tempo search surfaces it; flamegraph assembler renders it
         now_s = int(time.time())

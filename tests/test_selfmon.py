@@ -53,6 +53,22 @@ def test_latency_histogram_quantiles():
     assert s["p99_ms"] >= 1000.0
 
 
+def test_latency_histogram_resolves_within_a_1_2_5_step():
+    """Quantiles land on the next 1-2-5 bound from 10 us to 100 s, not
+    on the next decade."""
+    bounds = LatencyHistogram.BOUNDS_NS
+    assert bounds[0] == 10_000 and bounds[-1] == 100_000_000_000
+    assert all(b / a <= 2.5 for a, b in zip(bounds, bounds[1:]))
+    for wait_ns, want_ms in ((3_000_000, 5.0), (150_000, 0.2),
+                             (1_200_000_000, 2000.0), (7_000, 0.01)):
+        h = LatencyHistogram()
+        h.observe(wait_ns, 4)
+        assert h.snapshot()["p50_ms"] == want_ms, wait_ns
+    h = LatencyHistogram()
+    h.observe(300_000_000_000)          # past the last bound: the mean
+    assert h.snapshot()["p99_ms"] == 300_000.0
+
+
 def test_hop_ledger_conservation():
     hop = HopLedger("sender")
     hop.account(emitted=10)

@@ -278,6 +278,78 @@ def test_xplane_capture_and_user_traces_exclude_each_other(tmp_path):
     jax.profiler.stop_trace()
 
 
+PHASES = ["probe.open", "probe.window", "probe.stop", "probe.parse",
+          "probe.sink"]
+
+
+def test_capture_cycle_span_tiles_its_phases(monkeypatch):
+    """Each capture is one probe.cycle trace whose children tile it in
+    order, on the time.time_ns() clock; the time to the next capture is
+    a probe.gap of the same trace, and every phase also leaves as a
+    DFSTATS phase duration."""
+    from deepflow_tpu.query import qtrace
+    from deepflow_tpu.telemetry import Telemetry
+    from deepflow_tpu.tpuprobe.sources import XPlaneSource
+
+    monkeypatch.setenv("DF_QUERY_TRACE", "1")
+    monkeypatch.setenv("DF_QUERY_TRACE_SAMPLE", "1")
+    tel = Telemetry("agent", enabled=True)
+    src = XPlaneSource(lambda e: None, duration_ms=200, telemetry=tel)
+    before = time.time_ns()
+    src.capture_once()
+    src.capture_once()
+    src.tracer.flush()
+    spans = src.tracer.sink.snapshot()
+    cycles = [s for s in spans if s["name"] == "probe.cycle"]
+    assert len(cycles) == 2
+    first, second = sorted(cycles, key=lambda s: s["start_ns"])
+    assert before <= first["start_ns"] < first["end_ns"] \
+        <= second["start_ns"]
+    assert first["parent_span_id"] == ""
+    assert first["attrs"]["gap_bound"] == "fallback"   # no steps yet
+    assert first["attrs"]["planned_gap_ms"] == src.interval_s * 1000
+    assert first["attrs"]["planned_window_ms"] == 200.0
+    kids = sorted((s for s in spans
+                   if s["parent_span_id"] == first["span_id"]
+                   and s["name"] != "probe.gap"),
+                  key=lambda s: s["start_ns"])
+    assert [s["name"] for s in kids] == PHASES
+    assert kids[0]["start_ns"] == first["start_ns"]
+    assert kids[-1]["end_ns"] == first["end_ns"]
+    for a, b in zip(kids, kids[1:]):
+        assert a["end_ns"] == b["start_ns"], (a, b)
+    assert sum(s["duration_ns"] for s in kids) == first["duration_ns"]
+    assert kids[1]["duration_ns"] >= 190_000_000     # the window
+    gap, = [s for s in spans if s["name"] == "probe.gap"]
+    assert gap["trace_id"] == first["trace_id"]
+    assert gap["parent_span_id"] == first["span_id"]
+    assert (gap["start_ns"], gap["end_ns"]) == (first["end_ns"],
+                                                second["start_ns"])
+    assert qtrace.active() is False
+    phases = {p["phase"]: p for p in tel.phases_snapshot()
+              if p["stage"] == "tpuprobe.cycle"}
+    assert set(phases) == {"open", "window", "stop", "parse", "sink", "gap"}
+    assert phases["window"]["count"] == 2 and phases["gap"]["count"] == 1
+    last_sink, = [s for s in spans if s["name"] == "probe.sink"
+                  and s["parent_span_id"] == second["span_id"]]
+    assert src.stats["sink_ms"] == round(last_sink["duration_ns"] / 1e6, 1)
+    assert "coverage_pct" not in src.stats and "captured_s" not in src.stats
+
+
+def test_device_clock_offsets_read_line_timestamps():
+    from deepflow_tpu.tpuprobe.xplane import (
+        XEventView, XLineView, XPlaneView, device_clock_offsets)
+    ops = XLineView("XLA Ops", 1_000_500, [
+        XEventView("fusion.2", "", 9_000_000, 1),
+        XEventView("fusion.1", "", 2_000_000, 1)])
+    planes = [XPlaneView("/host:CPU", [XLineView("x", 5)]),
+              XPlaneView("/device:TPU:0",
+                         [XLineView("XLA Modules", 1_000_200), ops])]
+    assert device_clock_offsets(planes, 1_000_000) == {
+        "xline_offset_ns": 200, "first_op_offset_ns": 500 + 2_000}
+    assert device_clock_offsets(planes[:1], 1_000_000) == {}
+
+
 def test_xplane_contention_guard():
     """A second source (or user profiling) never collides — the window is
     skipped and counted."""
