@@ -84,6 +84,9 @@ class QuerierAPI:
         # local /v1/query path and the shard half of federated scatters
         from deepflow_tpu.query.cache import QueryCache
         self.query_cache = QueryCache(telemetry=telemetry)
+        # TpuFlame's last (aggregate rows, tree): a dashboard refresh
+        # that aggregates to the same rows reuses the built tree
+        self._flame_memo: tuple | None = None
         # read-tier wiring (set by server/server.py when storage is
         # disaggregated): the shard-side SegmentPublisher (for the
         # publish-gen exclusion handshake), the querier-side ReadTier,
@@ -558,15 +561,24 @@ class QuerierAPI:
                 res, info = fed.sql_query(table, qsql.parse(sql_text),
                                           sql_text)
             else:
-                res = qengine.execute(table, sql_text)
+                res = self.query_cache.execute(table, sql_text)
             ex.annotate(rows=len(res.values))
         from deepflow_tpu.query.flamegraph import build_flame_tree
-        stacks, values = [], []
-        for mod, cat, op, d in res.values:
-            stacks.append(";".join(x for x in (mod, cat or "other", op) if x))
-            values.append(int(d))
-        with qtrace.span("flame.build"):
-            out = {"result": build_flame_tree(stacks, values).to_dict()}
+        with qtrace.span("flame.build") as fb:
+            memo = (self._flame_memo if self.query_cache.enabled()
+                    else None)
+            if memo is not None and memo[0] == res.values:
+                tree = memo[1]  # read-only: responses only serialize it
+                fb.annotate(reused=True)
+            else:
+                stacks, values = [], []
+                for mod, cat, op, d in res.values:
+                    stacks.append(";".join(
+                        x for x in (mod, cat or "other", op) if x))
+                    values.append(int(d))
+                tree = build_flame_tree(stacks, values).to_dict()
+                self._flame_memo = (res.values, tree)
+        out = {"result": tree}
         if info is not None:
             out["federation"] = info
         return out
@@ -617,7 +629,7 @@ class QuerierAPI:
             swhere.append(f"time < {int(body['time_end'])}")
         top_n = int(body.get("top", 15))
         with qtrace.span("execute", path="tpu_memory.top_ops") as ex:
-            sres = qengine.execute(
+            sres = self.query_cache.execute(
                 spans, "SELECT hlo_op, hlo_module, Sum(bytes_accessed) AS b, "
                        "Sum(duration_ns) AS d, Count() AS n FROM t "
                        f"WHERE {' AND '.join(swhere)} "
@@ -636,7 +648,7 @@ class QuerierAPI:
                         key=lambda s: s["bytes_in_use"] / s["bytes_limit"])
             w = int(body.get("forensics_window_s", 10)) * 1_000_000_000
             t0, t1 = worst["time"] - w, worst["time"] + w
-            fres = qengine.execute(
+            fres = self.query_cache.execute(
                 spans, "SELECT hlo_op, Sum(bytes_accessed) AS b FROM t "
                        f"WHERE bytes_accessed > 0 AND time >= {t0} "
                        f"AND time < {t1} GROUP BY hlo_op "

@@ -82,6 +82,7 @@ model can see what the writer actually picked (satellite of ISSUE 11).
 
 from __future__ import annotations
 
+import itertools
 import json
 import mmap
 import os
@@ -98,6 +99,10 @@ MAGIC_V2 = b"DFSEG002"
 TAIL_MAGIC = b"DFSEGEND"
 _TAIL = struct.Struct("<II8s")  # footer_len, footer_crc32, tail magic
 _ALIGN = 64
+
+# serials of immutable row sets (sealed table chunks, segments): one
+# process-wide sequence, so a serial is never reused
+next_serial = itertools.count(1).__next__
 
 # compress a column block only when it saves at least this fraction —
 # a raw block is an mmap zero-copy view, which is worth real bytes
@@ -626,10 +631,14 @@ class Segment:
 
     __slots__ = ("path", "rows", "tmin", "tmax", "time_col", "dict_gens",
                  "nbytes", "zones", "fmt", "run", "sorted_by", "_mm",
-                 "_cols", "_cache", "_lock", "_indexes", "_crc_ok")
+                 "_cols", "_cache", "_lock", "_indexes", "_crc_ok",
+                 "serial")
 
     def __init__(self, path: str, footer: dict, mm, nbytes: int) -> None:
         self.path = path
+        # identity of this immutable row set for the query cache's
+        # chunk partials; a reopened or repaired file gets a new one
+        self.serial = next_serial()
         self.rows = int(footer["rows"])
         self.tmin = footer.get("tmin")
         self.tmax = footer.get("tmax")

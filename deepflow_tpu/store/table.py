@@ -19,6 +19,7 @@ import numpy as np
 
 from deepflow_tpu.native import ArenaStrings
 from deepflow_tpu.store.dictionary import Dictionary
+from deepflow_tpu.store.segment import next_serial
 
 _DTYPES = {
     "u8": np.uint8, "u16": np.uint16, "u32": np.uint32, "u64": np.uint64,
@@ -61,6 +62,24 @@ class _Stripe:
         # seal/refill cycle and would validate a stale memo.
         self.seq = 0
         self.mat: tuple[int, dict] | None = None
+
+
+class SealedChunk(dict):
+    """A chunk whose rows never change again: {column -> ndarray} plus a
+    serial no other chunk gets and the time extent (tmin, tmax; None
+    without a time column), both fixed when it is built. A rebuilt
+    chunk (flush merge, dictionary remap, load) is a new SealedChunk."""
+
+    __slots__ = ("serial", "tmin", "tmax")
+
+    def __init__(self, cols: dict, time_col: str | None) -> None:
+        super().__init__(cols)
+        self.serial = next_serial()
+        t = cols.get(time_col) if time_col else None
+        if t is not None and len(t) and t.dtype.kind in "iu":
+            self.tmin, self.tmax = int(t.min()), int(t.max())
+        else:
+            self.tmin = self.tmax = None
 
 
 class ColumnarTable:
@@ -363,6 +382,7 @@ class ColumnarTable:
         s.rows = 0
         s.seq += 1
         s.mat = None
+        chunk = SealedChunk(chunk, self._time_col)
         with self._lock:
             self._chunks.append(chunk)
 
@@ -415,10 +435,10 @@ class ColumnarTable:
             parts = list(self._pending_flush)
         if not parts:
             return None
-        merged = ({name: self._materialize([ch[name] for ch in parts],
-                                           spec)
-                   for name, spec in self.columns.items()}
-                  if len(parts) > 1 else parts[0])
+        merged = (SealedChunk({name: self._materialize(
+            [ch[name] for ch in parts], spec)
+            for name, spec in self.columns.items()}, self._time_col)
+            if len(parts) > 1 else parts[0])
         with self._lock:
             self._pending_flush = [merged]
         rows = len(next(iter(merged.values()))) if merged else 0
@@ -545,6 +565,35 @@ class ColumnarTable:
                 units.append((chunk, None, None))
         return units
 
+    def chunk_units(self) -> list[tuple[tuple, int | None,
+                                        int | None, int | None]]:
+        """scan_units() for the query cache's chunk partials
+        (query/cache.py): the same snapshot, under the same locking and
+        in the same order, as (unit, serial, tmin, tmax). A unit whose
+        rows never change (a sealed or staged chunk, a tier segment)
+        carries its serial and the time extent fixed when it was built;
+        a live unit (an open stripe's rows) carries serial None and the
+        extent of the rows it holds now. tmin/tmax are None where the
+        extent is unknown: no time column, or a remote segment."""
+        tc = self._time_col
+        out = []
+        for unit in self.scan_units():
+            ch, _z, seg = unit
+            if isinstance(ch, SealedChunk):
+                out.append((unit, ch.serial, ch.tmin, ch.tmax))
+            elif seg is not None:
+                ok = getattr(seg, "time_col", None) == tc
+                out.append((unit, getattr(seg, "serial", None),
+                            getattr(seg, "tmin", None) if ok else None,
+                            getattr(seg, "tmax", None) if ok else None))
+            else:
+                t = ch.get(tc) if tc else None
+                known = t is not None and len(t) and t.dtype.kind in "iu"
+                out.append((unit, None,
+                            *((int(t.min()), int(t.max())) if known
+                              else (None, None))))
+        return out
+
     def column_concat(self, names: list[str],
                       mask_chunks: list[np.ndarray] | None = None,
                       chunks: list[dict[str, np.ndarray]] | None = None
@@ -657,7 +706,9 @@ class ColumnarTable:
                     for new_id, old_id in enumerate(order, start=1):
                         lut[old_id] = new_id
                     self._chunks = [
-                        {**ch, name: lut[ch[name]]} for ch in self._chunks]
+                        SealedChunk({**ch, name: lut[ch[name]]},
+                                    self._time_col)
+                        for ch in self._chunks]
                     for s in stripes:
                         s.buf[name] = [
                             lut[seg] if isinstance(seg, np.ndarray)
@@ -763,7 +814,7 @@ class ColumnarTable:
                                 ch[name] = np.full(n,
                                                    self._fill(name, spec),
                                                    dtype=spec.np_dtype)
-                    self._chunks.append(ch)
+                    self._chunks.append(SealedChunk(ch, self._time_col))
             for name in self.dicts:
                 p = os.path.join(dirpath, f"dict_{name}.json")
                 if os.path.exists(p):

@@ -304,12 +304,19 @@ def test_results_byte_identical_tracing_on_off(solo_server, monkeypatch):
     monkeypatch.setenv("DF_QUERY_TRACE", "1")
     on = _post(s.query_port, "/v1/query", {"db": "flow_log", "sql": sql})
     assert _canon(off["result"]) == _canon(on["result"])
-    # off really was off; on really wrote spans
-    s.api.qtracer.flush()
+    # off really was off; on really wrote spans. The request's root
+    # span closes just after the response is written, so poll for it
     from deepflow_tpu.query import engine
-    res = engine.execute(s.db.table("deepflow_system.query_trace"),
-                         "SELECT name, status FROM t")
-    assert ("execute", "ok") in {(v[0], v[1]) for v in res.values}
+    deadline = time.monotonic() + 5
+    while True:
+        s.api.qtracer.flush()
+        res = engine.execute(s.db.table("deepflow_system.query_trace"),
+                             "SELECT name, status FROM t")
+        seen = {(v[0], v[1]) for v in res.values}
+        if ("execute", "ok") in seen or time.monotonic() > deadline:
+            break
+        time.sleep(0.02)
+    assert ("execute", "ok") in seen
 
 
 def test_health_query_trace_block(solo_server):
